@@ -52,6 +52,7 @@ import gc
 import json
 import os
 import time
+from collections import Counter
 
 import numpy as np
 
@@ -95,11 +96,12 @@ def bench_sim(mix: str, zipf: float, steps: int, num_keys: int,
               jit: bool = False) -> dict:
     """Sampled-ops/s through TimedSimulation, scalar vs batched (and,
     with ``jit=True``, the compiled batch executor as a third leg with
-    its ENGINE_WALL breakdown -- the same-run basis for the write-plane
+    its engine-span breakdown -- the same-run basis for the write-plane
     gate)."""
-    from repro.core.transition import ENGINE_WALL, reset_engine_wall
+    from repro.core.telemetry import ENGINE_SPANS
     out = {}
     stats = {}
+    plan_counts = Counter()        # every leg's cluster, warm-up included
     gc_was_enabled = gc.isenabled()
     gc.disable()
     legs = [("scalar", True, False, SEED_SAMPLE_OPS, None),
@@ -116,15 +118,15 @@ def bench_sim(mix: str, zipf: float, steps: int, num_keys: int,
                                   dt=1.0, batched=batched, engine=engine,
                                   **kw)
             sim.run(2.0, lambda t: 1e8)                 # warm-up
-            c.pool.merge_wall_s = 0.0
-            _merge_plan_coverage()                      # reset counters
-            reset_engine_wall()
+            before = c.telemetry.snapshot()
             walls = []
             for _ in range(repeats):
                 gc.collect()
                 t0 = time.perf_counter()
                 sim.run(sim.now + steps, lambda t: 1e8)
                 walls.append(time.perf_counter() - t0)
+            spans, counts = _delta(before, c.telemetry.snapshot())
+            plan_counts.update(c.telemetry.counts)
             best = min(walls)
             out[label] = {
                 "sampled_ops_per_s": steps * sim.sample_ops / best,
@@ -135,8 +137,10 @@ def bench_sim(mix: str, zipf: float, steps: int, num_keys: int,
                 # PR 4 tracking: share of the measured wall spent in the
                 # staged merge plane (merge_budget + merge_all), and the
                 # fraction of merged entries the MergeWindowPlan covered
-                "merge_wall_share": c.pool.merge_wall_s / sum(walls),
-                "merge_plan_coverage": _merge_plan_coverage(),
+                "merge_wall_share": spans.get("pool.merge", 0.0)
+                / sum(walls),
+                "merge_plan_coverage": _coverage(counts, "merge_plan",
+                                                 "entries"),
             }
             if batched:
                 # window-engine wall breakdown over the measured
@@ -145,9 +149,9 @@ def bench_sim(mix: str, zipf: float, steps: int, num_keys: int,
                 # residency sync) -- the compiled executor's dispatch
                 # itself is excluded
                 wall_total = sum(walls)
-                book = sum(v for k, v in ENGINE_WALL.items()
-                           if k != "jit_dispatch")
-                out[label]["engine_wall"] = dict(ENGINE_WALL)
+                wall = {k: spans.get(v, 0.0) for k, v in ENGINE_SPANS.items()}
+                book = sum(v for k, v in wall.items() if k != "jit_dispatch")
+                out[label]["engine_wall"] = wall
                 out[label]["bookkeeping_share"] = book / wall_total
                 stats[label] = c.aggregate_stats()
     finally:
@@ -164,30 +168,26 @@ def bench_sim(mix: str, zipf: float, steps: int, num_keys: int,
             / out["scalar"]["sampled_ops_per_s"])
     out["speedup"] = (out["batched"]["sampled_ops_per_s"]
                       / out["scalar"]["sampled_ops_per_s"])
-    out["plan_coverage"] = _plan_coverage()
+    out["plan_coverage"] = _coverage(plan_counts, "plan", "ops")
     return out
 
 
-def _plan_coverage() -> float:
-    """Fraction of window ops the planned-transition engine planned
-    (vs replayed per-op) since the last reset -- PR 3 tracking."""
-    from repro.core.transition import PLAN_STATS, reset_plan_stats
-    total = PLAN_STATS["planned_ops"] + PLAN_STATS["replayed_ops"]
-    cov = PLAN_STATS["planned_ops"] / total if total else 0.0
-    reset_plan_stats()
-    return cov
+def _delta(before: dict, after: dict):
+    """Span totals and counts between two ``Telemetry.snapshot()``s."""
+    spans = {n: r["total_s"] - before["spans"].get(n, {}).get("total_s", 0.0)
+             for n, r in after["spans"].items()}
+    counts = Counter(after["counts"])
+    counts.subtract(before["counts"])
+    return spans, counts
 
 
-def _merge_plan_coverage() -> float:
-    """Fraction of merged entries the planned merge plane covered (vs
-    scalar replay) since the last reset -- PR 4 tracking."""
-    from repro.core.transition import (MERGE_PLAN_STATS,
-                                       reset_merge_plan_stats)
-    total = (MERGE_PLAN_STATS["planned_entries"]
-             + MERGE_PLAN_STATS["replayed_entries"])
-    cov = MERGE_PLAN_STATS["planned_entries"] / total if total else 0.0
-    reset_merge_plan_stats()
-    return cov
+def _coverage(counts, plane: str, unit: str) -> float:
+    """Fraction of window ops (``plan``, PR 3) or merged entries
+    (``merge_plan``, PR 4) that the planned path covered, vs scalar
+    replay."""
+    planned = counts[f"{plane}.planned_{unit}"]
+    total = planned + counts[f"{plane}.replayed_{unit}"]
+    return planned / total if total else 0.0
 
 
 def bench_merge_plane(n_entries: int = 40_000, reps: int = 3) -> dict:
@@ -211,11 +211,12 @@ def bench_merge_plane(n_entries: int = 40_000, reps: int = 3) -> dict:
             pool.log_write_batch("kn1", keys,
                                  [f"v{i}" for i in range(n_entries)],
                                  [64] * n_entries)
-            _merge_plan_coverage()
+            before = pool.telemetry.snapshot()
             t0 = time.perf_counter()
             pool.merge_all()
             walls.append(time.perf_counter() - t0)
-            cov = _merge_plan_coverage()
+            cov = _coverage(_delta(before, pool.telemetry.snapshot())[1],
+                            "merge_plan", "entries")
         out[label] = {"entries_per_s": n_entries / min(walls),
                       "wall_s": min(walls),
                       "plan_coverage": cov}

@@ -185,7 +185,8 @@ def run(keys: int, batches: int = BATCHES_PER_MIX, seed: int = SEED):
     eng = chip._jit
     if eng is None:
         raise AssertionError("no window reached the jit engine")
-    cnt = eng.counts
+    tel = chip.telemetry
+    cnt = tel.counts
     host_ops = {k.split(".", 1)[1]: v for k, v in sorted(cnt.items())
                 if k.startswith("host_ops.")}
     log(f"device: dispatches={cnt['dispatches']} "
@@ -198,7 +199,8 @@ def run(keys: int, batches: int = BATCHES_PER_MIX, seed: int = SEED):
     log(f"memory: resident_state_bytes_peak={eng.resident_bytes_peak} "
         f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
         f"bytes_limit={stats.get('bytes_limit')}")
-    log(f"setup: upload {eng.upload_s:.1f} s (host wall), backend compile "
+    log(f"setup: upload {tel.total('jit.upload'):.1f} s (host wall), "
+        f"{cnt['jit.upload_bytes']} B; backend compile "
         f"{compiles['s']:.1f} s over {compiles['n']} compiles")
     return chip
 

@@ -20,10 +20,10 @@ from .ownership import OwnershipMap, ReconfigEvent
 from .requestplane import (OpRecord, RequestPlane, RequestPlaneConfig,
                            RequestPlaneResult)
 from .simulate import TimedSimulation
-from .transition import (PLAN_STATS, DacWindowPlan, StaticWindowPlan,
+from .telemetry import Telemetry
+from .transition import (DacWindowPlan, StaticWindowPlan,
                          CloverReadPlan, plan_clover_reads,
-                         plan_dac_window, plan_static_window,
-                         reset_plan_stats)
+                         plan_dac_window, plan_static_window)
 
 __all__ = [
     "DinomoCluster", "VariantConfig", "BatchResult", "DINOMO",
@@ -39,7 +39,6 @@ __all__ = [
     "DEFAULT_MODEL", "ArrivalProcess", "PhasedArrival", "OpRecord",
     "RequestPlane", "RequestPlaneConfig", "RequestPlaneResult",
     "OwnershipMap", "ReconfigEvent", "TimedSimulation",
-    "PLAN_STATS", "DacWindowPlan", "StaticWindowPlan", "CloverReadPlan",
+    "Telemetry", "DacWindowPlan", "StaticWindowPlan", "CloverReadPlan",
     "plan_dac_window", "plan_static_window", "plan_clover_reads",
-    "reset_plan_stats",
 ]

@@ -31,8 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .transition import (MERGE_PLAN_STATS, MERGE_MAX_CHAIN,
-                         _merge_bucket_batch, plan_merge_window)
+from .telemetry import Telemetry
+from .transition import (MERGE_MAX_CHAIN, _merge_bucket_batch,
+                         plan_merge_window)
 
 EMPTY = jnp.int32(-1)
 SLOTS = 3          # one cache line, as in P-CLHT
@@ -243,8 +244,11 @@ class NumpyCLHT:
     cluster simulator. ``probes`` returned by lookup equals the number of
     bucket rows (cache lines / one-sided reads) touched."""
 
-    def __init__(self, num_buckets: int, overflow_buckets: int | None = None):
+    def __init__(self, num_buckets: int, overflow_buckets: int | None = None,
+                 telemetry: Telemetry | None = None):
         assert num_buckets & (num_buckets - 1) == 0
+        # the owning pool's (merge_plan.* coverage counts)
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         if overflow_buckets is None:
             overflow_buckets = max(num_buckets // 2, 8)
         total = num_buckets + overflow_buckets
@@ -353,6 +357,7 @@ class NumpyCLHT:
         old = np.full(n, -1, np.int64)
         ok = np.ones(n, bool)
         grown: list[int] = []
+        counts = self.telemetry.counts
         i = 0
         while i < n:
             plan = plan_merge_window(self, keys[i:], ptrs[i:],
@@ -365,14 +370,14 @@ class NumpyCLHT:
                 if o is not None:
                     old[i] = o
                 ok[i] = okk
-                MERGE_PLAN_STATS["replayed_windows"] += 1
-                MERGE_PLAN_STATS["replayed_entries"] += 1
+                counts["merge_plan.replayed_windows"] += 1
+                counts["merge_plan.replayed_entries"] += 1
                 i += 1
                 continue
             self.apply_merge_plan(plan)
             old[i:i + plan.ops] = plan.old
-            MERGE_PLAN_STATS["planned_windows"] += 1
-            MERGE_PLAN_STATS["planned_entries"] += plan.ops
+            counts["merge_plan.planned_windows"] += 1
+            counts["merge_plan.planned_entries"] += plan.ops
             i += plan.ops
         return old, ok, grown
 

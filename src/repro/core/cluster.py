@@ -32,9 +32,9 @@ from .mnode import PolicyConfig, PolicyEngine
 from .netmodel import NetModel, DEFAULT_MODEL
 from .hashring import stable_hash
 from .ownership import OwnershipMap, ReconfigEvent
-from .transition import (ENGINE_WALL, PLAN_STATS, plan_clover_reads,
-                         plan_dac_window, plan_static_window)
-from time import perf_counter
+from .telemetry import Telemetry
+from .transition import (plan_clover_reads, plan_dac_window,
+                         plan_static_window)
 
 
 @dataclass(frozen=True)
@@ -331,8 +331,12 @@ class DinomoCluster:
         self.model = model
         self.value_bytes = value_bytes
         self.cache_bytes = cache_bytes
+        # this cluster's spans and counters, shared with its pool, index
+        # and jit driver (core/telemetry.py)
+        self.telemetry = Telemetry()
         self.pool = DPMPool(num_buckets=num_buckets,
-                            segment_capacity=segment_capacity)
+                            segment_capacity=segment_capacity,
+                            telemetry=self.telemetry)
         self.ownership = OwnershipMap(vnodes=vnodes)
         self.kns: dict[str, KVSNode] = {}
         self.mnode = PolicyEngine(policy or PolicyConfig())
@@ -708,52 +712,59 @@ class DinomoCluster:
             return self._execute_batch_fused(kinds, keys, value, values,
                                              blocked_kns, out_values,
                                              req_ids)
-        return self._execute_batch_spans(kinds, keys, value, values,
-                                         blocked_kns, out_values, req_ids)
+        tel = self.telemetry
+        tel.batch += 1
+        with tel.span("cluster.execute_batch"):
+            return self._execute_batch_spans(kinds, keys, value, values,
+                                             blocked_kns, out_values,
+                                             req_ids)
 
     def _execute_batch_spans(self, kinds, keys, value, values, blocked_kns,
                              out_values, req_ids=None) -> "BatchResult":
         names = list(self.kns.keys())
         name_idx = {nm: j for j, nm in enumerate(names)}
         n = keys.shape[0]
+        tel = self.telemetry
 
-        # ----- vectorized routing over the ownership ring ------------------
-        ring_ids, ring_names = self.ownership.primary_ids(keys)
-        ring_to_kn = np.array([name_idx.get(nm, -1) for nm in ring_names],
-                              dtype=np.int64)
-        kn_ids = ring_to_kn[ring_ids]
-        rep_arr = self.ownership.replicated_keys_array()
-        if rep_arr.size:
-            rep_mask = np.isin(keys, rep_arr)
-            for p in np.nonzero(rep_mask)[0]:
-                try:   # replicated keys draw a random owner, as scalar
-                    kn_ids[p] = name_idx[self.route(int(keys[p]))]
-                except KeyError:
-                    kn_ids[p] = -1
-        else:
-            rep_mask = np.zeros(n, bool)
+        # ----- vectorized routing over the ownership ring, availability ----
+        with tel.span("cluster.route"):
+            ring_ids, ring_names = self.ownership.primary_ids(keys)
+            ring_to_kn = np.array([name_idx.get(nm, -1)
+                                   for nm in ring_names], dtype=np.int64)
+            kn_ids = ring_to_kn[ring_ids]
+            rep_arr = self.ownership.replicated_keys_array()
+            if rep_arr.size:
+                rep_mask = np.isin(keys, rep_arr)
+                for p in np.nonzero(rep_mask)[0]:
+                    try:   # replicated keys draw a random owner, as scalar
+                        kn_ids[p] = name_idx[self.route(int(keys[p]))]
+                    except KeyError:
+                        kn_ids[p] = -1
+            else:
+                rep_mask = np.zeros(n, bool)
 
-        # ----- availability masks ------------------------------------------
-        blocked = np.zeros(len(names), bool)
-        for nm in blocked_kns:
-            j = name_idx.get(nm)
-            if j is not None:
-                blocked[j] = True
-        refusing = np.array([not (self.kns[nm].alive
-                                  and self.kns[nm].available)
-                             for nm in names], bool)
-        safe_ids = np.maximum(kn_ids, 0)
-        exec_mask = (kn_ids >= 0) & ~blocked[safe_ids]
-        refused_mask = exec_mask & refusing[safe_ids]
-        live = exec_mask & ~refused_mask
-        rcnt = np.bincount(kn_ids[refused_mask], minlength=len(names))
-        for j in np.nonzero(rcnt)[0]:
-            self.kns[names[j]].stats.refused += int(rcnt[j])
+            blocked = np.zeros(len(names), bool)
+            for nm in blocked_kns:
+                j = name_idx.get(nm)
+                if j is not None:
+                    blocked[j] = True
+            refusing = np.array([not (self.kns[nm].alive
+                                      and self.kns[nm].available)
+                                 for nm in names], bool)
+            safe_ids = np.maximum(kn_ids, 0)
+            exec_mask = (kn_ids >= 0) & ~blocked[safe_ids]
+            refused_mask = exec_mask & refusing[safe_ids]
+            live = exec_mask & ~refused_mask
+            rcnt = np.bincount(kn_ids[refused_mask],
+                               minlength=len(names))
+            for j in np.nonzero(rcnt)[0]:
+                self.kns[names[j]].stats.refused += int(rcnt[j])
 
         # ----- stage the write plane ---------------------------------------
         pool = self.pool
-        plan = self._build_write_plan(kinds, keys, kn_ids, live, names,
-                                      value, values, req_ids)
+        with tel.span("cluster.write_plan"):
+            plan = self._build_write_plan(kinds, keys, kn_ids, live,
+                                          names, value, values, req_ids)
 
         # ----- per-KN windows + predicted-miss probe prefetch --------------
         # (one vectorized CLHT gather replaces per-key chain walks; each
@@ -764,24 +775,29 @@ class DinomoCluster:
         probe_map: dict[int, tuple] = {}
         dkeys, dbuckets = pool.track_merge_dirty()
         windows = []
-        for grp in self._kn_groups(np.nonzero(live & ~rep_mask)[0], kn_ids):
-            kn = self.kns[names[int(kn_ids[grp[0]])]]
-            cache = kn.cache
-            # grow the per-key vectors up front: the window loops cache
-            # bound accessors, so the arrays must not move mid-batch
-            cache._ensure(int(keys[grp].max()))
-            rsub = grp[kinds[grp] == 0]
-            if rsub.size:
-                pm = rsub[cache.kind[keys[rsub]] == 0]
-                if pm.size:
-                    pk = keys[pm]
-                    pptr, pprob = pool.index_lookup_batch(pk)
-                    pbuck = pool.index._bucket_batch(pk)
-                    for p_, pp, pb, bk in zip(pm.tolist(), pptr.tolist(),
-                                              pprob.tolist(),
-                                              pbuck.tolist()):
-                        probe_map[p_] = (None if pp < 0 else pp, pb, bk)
-            windows.append(_KnWindow(kn, cache, grp))
+        with tel.span("cluster.probe_prefetch"):
+            for grp in self._kn_groups(np.nonzero(live & ~rep_mask)[0],
+                                       kn_ids):
+                kn = self.kns[names[int(kn_ids[grp[0]])]]
+                cache = kn.cache
+                # grow the per-key vectors up front: the window loops
+                # cache bound accessors, so the arrays must not move
+                # mid-batch
+                cache._ensure(int(keys[grp].max()))
+                rsub = grp[kinds[grp] == 0]
+                if rsub.size:
+                    pm = rsub[cache.kind[keys[rsub]] == 0]
+                    if pm.size:
+                        pk = keys[pm]
+                        pptr, pprob = pool.index_lookup_batch(pk)
+                        pbuck = pool.index._bucket_batch(pk)
+                        for p_, pp, pb, bk in zip(pm.tolist(),
+                                                  pptr.tolist(),
+                                                  pprob.tolist(),
+                                                  pbuck.tolist()):
+                            probe_map[p_] = (None if pp < 0 else pp, pb,
+                                             bk)
+                windows.append(_KnWindow(kn, cache, grp))
 
         # ----- event-driven coordination -----------------------------------
         # Global events order the cross-KN interactions exactly as the
@@ -850,24 +866,25 @@ class DinomoCluster:
             pool.untrack_merge_dirty()
 
         # ----- finalize -----------------------------------------------------
-        for nm in plan.segq:
-            self._fill_planned_segment(plan, nm, final=True)
-        for nm, c in stalls.items():
-            self.kns[nm].stats.write_stalls += c
-        nw = plan.nw
-        if nw:
-            vs = self.versions
-            uk, uc = np.unique(plan.wkeys, return_counts=True)
-            for k, c in zip(uk.tolist(), uc.tolist()):
-                vs[k] = vs.get(k, 0) + c
-            self._seq += nw
-        cnt = np.bincount(kn_ids[exec_mask], minlength=len(names))
-        per_kn = {names[j]: int(cnt[j]) for j in np.nonzero(cnt)[0]}
-        # scalar loops count refused writes too (the write() call refuses
-        # after the attempt is recorded by the driver)
-        writes = nw + int((kinds[refused_mask] != 0).sum())
-        return BatchResult(int(exec_mask.sum()), writes, per_kn,
-                           keys[exec_mask], out_values)
+        with tel.span("cluster.finalize"):
+            for nm in plan.segq:
+                self._fill_planned_segment(plan, nm, final=True)
+            for nm, c in stalls.items():
+                self.kns[nm].stats.write_stalls += c
+            nw = plan.nw
+            if nw:
+                vs = self.versions
+                uk, uc = np.unique(plan.wkeys, return_counts=True)
+                for k, c in zip(uk.tolist(), uc.tolist()):
+                    vs[k] = vs.get(k, 0) + c
+                self._seq += nw
+            cnt = np.bincount(kn_ids[exec_mask], minlength=len(names))
+            per_kn = {names[j]: int(cnt[j]) for j in np.nonzero(cnt)[0]}
+            # scalar loops count refused writes too (the write() call
+            # refuses after the attempt is recorded by the driver)
+            writes = nw + int((kinds[refused_mask] != 0).sum())
+            return BatchResult(int(exec_mask.sum()), writes, per_kn,
+                               keys[exec_mask], out_values)
 
     def _build_write_plan(self, kinds, keys, kn_ids, live, names, value,
                           values, req_ids=None) -> "_WritePlan":
@@ -1082,6 +1099,8 @@ class DinomoCluster:
         is_dac = w.is_dac
         planner = plan_dac_window if is_dac else \
             (plan_static_window if w.is_static else None)
+        tel = self.telemetry
+        counts = tel.counts
         collect = out_values is not None
         start = 0
         n_all = full.size
@@ -1096,16 +1115,16 @@ class DinomoCluster:
                 if oddballs == 0:
                     # pure value-hit window (the high-skew read-only
                     # regime): one bulk scatter, no planning overhead
-                    PLAN_STATS["planned_windows"] += 1
-                    PLAN_STATS["planned_ops"] += int(span.size)
+                    counts["plan.planned_windows"] += 1
+                    counts["plan.planned_ops"] += int(span.size)
                     self._vh_run_big(kn, cache, span, skeys, probe_map,
                                      dkeys, dbuckets, out_values)
                     return
                 if oddballs * 32 < span.size:
                     # hit-dominated read window: the run machinery's
                     # bulk value-hit path beats planning overhead
-                    PLAN_STATS["replayed_windows"] += 1
-                    PLAN_STATS["replayed_ops"] += int(span.size)
+                    counts["plan.replayed_windows"] += 1
+                    counts["plan.replayed_ops"] += int(span.size)
                     self._replay_span(kn, cache, is_dac, span, skeys,
                                       sops, plan, probe_map, dkeys,
                                       dbuckets, out_values)
@@ -1114,20 +1133,19 @@ class DinomoCluster:
             # the first op it cannot prove (wp.ops tells how far it
             # got), so planning work stays linear in the window
             end = min(span.size, 512)
-            t0 = perf_counter()
-            wp = planner(cache, kn, skeys[:end], sops[:end], span[:end],
-                         plan, probe_map, dkeys, dbuckets, self.pool,
-                         self.value_bytes, collect) \
-                if planner is not None else None
-            ENGINE_WALL["host_plan"] += perf_counter() - t0
+            with tel.span("host.plan", kn=kn.name):
+                wp = planner(cache, kn, skeys[:end], sops[:end],
+                             span[:end], plan, probe_map, dkeys, dbuckets,
+                             self.pool, self.value_bytes, collect) \
+                    if planner is not None else None
             if wp is not None:
                 end = wp.ops
-                PLAN_STATS["planned_windows"] += 1
-                PLAN_STATS["planned_ops"] += end
+                counts["plan.planned_windows"] += 1
+                counts["plan.planned_ops"] += end
                 self._apply_window_plan(kn, cache, wp, out_values)
             else:
-                PLAN_STATS["replayed_windows"] += 1
-                PLAN_STATS["replayed_ops"] += end
+                counts["plan.replayed_windows"] += 1
+                counts["plan.replayed_ops"] += end
                 self._replay_span(kn, cache, is_dac, span[:end],
                                   skeys[:end], sops[:end], plan,
                                   probe_map, dkeys, dbuckets,
@@ -1140,98 +1158,96 @@ class DinomoCluster:
         kind-gather, split into maximal same-class runs, apply
         vectorizable runs in bulk (re-validated against the live cache
         at run boundaries), drop to the exact scalar op otherwise."""
-        t0_wall = perf_counter()
-        cls = np.where(sops == 0, cache.kind[skeys],
-                       np.where(sops == 1, np.int8(3), np.int8(4)))
-        m = span.size
-        bnd = np.nonzero(cls[1:] != cls[:-1])[0] + 1
-        starts = (0, *bnd.tolist())
-        ends = (*bnd.tolist(), m)
-        cls_l = cls.tolist()
-        span_l = keys_l = None
-        for s, e in zip(starts, ends):
-            c = cls_l[s]
-            if c == 2 and e - s >= 48:
-                # a long value-hit run stays in numpy end to end
-                self._vh_run_big(kn, cache, span[s:e], skeys[s:e],
-                                 probe_map, dkeys, dbuckets, out_values)
-                continue
-            if span_l is None:
-                span_l = span.tolist()
-                keys_l = skeys.tolist()
-            if c == 2:
-                if is_dac:
-                    self._vh_run(kn, cache, span_l[s:e], keys_l[s:e],
-                                 probe_map, dkeys, dbuckets, out_values)
+        with self.telemetry.span("host.replay", kn=kn.name):
+            cls = np.where(sops == 0, cache.kind[skeys],
+                           np.where(sops == 1, np.int8(3), np.int8(4)))
+            m = span.size
+            bnd = np.nonzero(cls[1:] != cls[:-1])[0] + 1
+            starts = (0, *bnd.tolist())
+            ends = (*bnd.tolist(), m)
+            cls_l = cls.tolist()
+            span_l = keys_l = None
+            for s, e in zip(starts, ends):
+                c = cls_l[s]
+                if c == 2 and e - s >= 48:
+                    # a long value-hit run stays in numpy end to end
+                    self._vh_run_big(kn, cache, span[s:e], skeys[s:e],
+                                     probe_map, dkeys, dbuckets, out_values)
+                    continue
+                if span_l is None:
+                    span_l = span.tolist()
+                    keys_l = skeys.tolist()
+                if c == 2:
+                    if is_dac:
+                        self._vh_run(kn, cache, span_l[s:e], keys_l[s:e],
+                                     probe_map, dkeys, dbuckets, out_values)
+                    else:
+                        self._hit_run_static(kn, cache, span_l[s:e],
+                                             keys_l[s:e], c, probe_map,
+                                             dkeys, dbuckets, out_values)
+                elif c == 1:
+                    if is_dac:
+                        self._sc_run(kn, cache, span_l[s:e], keys_l[s:e],
+                                     probe_map, dkeys, dbuckets, out_values)
+                    else:
+                        self._hit_run_static(kn, cache, span_l[s:e],
+                                             keys_l[s:e], c, probe_map,
+                                             dkeys, dbuckets, out_values)
+                elif c >= 3:
+                    if is_dac:
+                        self._write_run(kn, cache, span_l[s:e], keys_l[s:e],
+                                        c == 4, plan, out_values)
+                    else:
+                        self._write_run_generic(kn, cache, span_l[s:e],
+                                                keys_l[s:e], c == 4, plan,
+                                                out_values)
                 else:
-                    self._hit_run_static(kn, cache, span_l[s:e],
-                                         keys_l[s:e], c, probe_map,
-                                         dkeys, dbuckets, out_values)
-            elif c == 1:
-                if is_dac:
-                    self._sc_run(kn, cache, span_l[s:e], keys_l[s:e],
-                                 probe_map, dkeys, dbuckets, out_values)
-                else:
-                    self._hit_run_static(kn, cache, span_l[s:e],
-                                         keys_l[s:e], c, probe_map,
-                                         dkeys, dbuckets, out_values)
-            elif c >= 3:
-                if is_dac:
-                    self._write_run(kn, cache, span_l[s:e], keys_l[s:e],
-                                    c == 4, plan, out_values)
-                else:
-                    self._write_run_generic(kn, cache, span_l[s:e],
-                                            keys_l[s:e], c == 4, plan,
-                                            out_values)
-            else:
-                # predicted misses: exact scalar ops
-                for p_, k in zip(span_l[s:e], keys_l[s:e]):
-                    self._scalar_read_dac(kn, cache, k, p_, probe_map,
-                                          dkeys, dbuckets, out_values)
-        ENGINE_WALL["host_replay"] += perf_counter() - t0_wall
+                    # predicted misses: exact scalar ops
+                    for p_, k in zip(span_l[s:e], keys_l[s:e]):
+                        self._scalar_read_dac(kn, cache, k, p_, probe_map,
+                                              dkeys, dbuckets, out_values)
 
     def _apply_window_plan(self, kn, cache, wp, out_values) -> None:
         """Apply a planned window: bulk cache mutation via apply_plan,
         then the kn-side effects (stats, miss-RT EMA in op order,
         segcache puts/pops, collected read values)."""
-        t0_wall = perf_counter()
-        cache.apply_plan(wp)
-        st = kn.stats
-        st.ops += wp.ops
-        st.reads += wp.reads
-        st.writes += wp.writes
-        st.rts += wp.rts
-        if wp.ema_rts:
-            ema = cache._ema
-            a = cache.avg_miss_rts
-            for r in wp.ema_rts:
-                a += ema * (r - a)
-            cache.avg_miss_rts = a
-        segd = kn.segcache
-        cap = kn.segcache_cap
-        if wp.seg_replay is not None:
-            vb = self.value_bytes
-            for k, p in wp.seg_replay:
-                if p is None:
-                    segd.pop(k, None)
-                else:
-                    segd[k] = (p, vb)
-                    segd.move_to_end(k)
-                    while len(segd) > cap:
-                        segd.popitem(last=False)
-        elif wp.seg_puts is not None:
-            ks, ps = wp.seg_puts
-            vb = self.value_bytes
-            segd.update(zip(ks, ((p, vb) for p in ps)))
-            # C-level move_to_end sweep keeps last-put order; trimming
-            # afterwards equals per-put trimming (LRU invariant)
-            any(map(segd.move_to_end, ks))
-            while len(segd) > cap:
-                segd.popitem(last=False)
-        if out_values is not None and wp.out_vals:
-            for p, v in wp.out_vals:
-                out_values[p] = v
-        ENGINE_WALL["host_apply"] += perf_counter() - t0_wall
+        with self.telemetry.span("host.apply", kn=kn.name):
+            cache.apply_plan(wp)
+            st = kn.stats
+            st.ops += wp.ops
+            st.reads += wp.reads
+            st.writes += wp.writes
+            st.rts += wp.rts
+            if wp.ema_rts:
+                ema = cache._ema
+                a = cache.avg_miss_rts
+                for r in wp.ema_rts:
+                    a += ema * (r - a)
+                cache.avg_miss_rts = a
+            segd = kn.segcache
+            cap = kn.segcache_cap
+            if wp.seg_replay is not None:
+                vb = self.value_bytes
+                for k, p in wp.seg_replay:
+                    if p is None:
+                        segd.pop(k, None)
+                    else:
+                        segd[k] = (p, vb)
+                        segd.move_to_end(k)
+                        while len(segd) > cap:
+                            segd.popitem(last=False)
+            elif wp.seg_puts is not None:
+                ks, ps = wp.seg_puts
+                vb = self.value_bytes
+                segd.update(zip(ks, ((p, vb) for p in ps)))
+                # C-level move_to_end sweep keeps last-put order; trimming
+                # afterwards equals per-put trimming (LRU invariant)
+                any(map(segd.move_to_end, ks))
+                while len(segd) > cap:
+                    segd.popitem(last=False)
+            if out_values is not None and wp.out_vals:
+                for p, v in wp.out_vals:
+                    out_values[p] = v
 
     def _vh_run(self, kn, cache, run_pos, run_keys, probe_map, dkeys,
                 dbuckets, out_values) -> None:

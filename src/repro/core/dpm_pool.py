@@ -16,7 +16,6 @@ timing/asynchrony is orchestrated by cluster.py against netmodel.py.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -26,8 +25,8 @@ from . import sanitize
 from .clht import NumpyCLHT
 from .faults import CRASH_POINTS, KNCrash
 from .log import PySegment
-from .transition import (MERGE_PLAN_STATS, MIN_MERGE_PLAN_OPS,
-                         plan_merge_window)
+from .telemetry import Telemetry
+from .transition import MIN_MERGE_PLAN_OPS, plan_merge_window
 
 
 @dataclass
@@ -59,7 +58,8 @@ class DPMPool:
     def __init__(self, num_buckets: int = 1 << 18,
                  segment_capacity: int = 2048,
                  unmerged_threshold: int = 2,
-                 vectorized: bool = True):
+                 vectorized: bool = True,
+                 telemetry: Telemetry | None = None):
         # ``vectorized=False`` keeps the per-entry merge path -- the
         # oracle the batched merge plane is property-tested against
         self.vectorized = vectorized
@@ -72,7 +72,10 @@ class DPMPool:
         # the batch engine uses them to spot prefetched index probes
         # that went stale mid-batch (key remapped / chain grew)
         self._dirty: tuple[set, set] | None = None
-        self.index = NumpyCLHT(num_buckets)
+        # the owning cluster's spans and counters (pool.merge span,
+        # merge_plan.* counts), shared with the index
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
+        self.index = NumpyCLHT(num_buckets, telemetry=self.telemetry)
         # value heap: ptr -> payload / length / owning segment
         self.heap_val: list = []
         self.heap_len: list[int] = []
@@ -82,9 +85,6 @@ class DPMPool:
         self.segment_capacity = segment_capacity
         self.unmerged_threshold = unmerged_threshold
         self.merge_backlog: deque[tuple[PySegment, int]] = deque()
-        # wall-clock spent inside merge_budget/merge_all: the bench's
-        # per-row merge wall-time share (PR 4 tracking)
-        self.merge_wall_s = 0.0
         # exactly-once retry contract (the open-loop request plane):
         # request IDs ride inside durable log entries; this table maps a
         # *sealed* entry's request ID to its heap pointer, so a client
@@ -433,10 +433,9 @@ class DPMPool:
         if self.merge_allowance is not None:
             ops = min(ops, self.merge_allowance)
         done = 0
-        t0 = time.perf_counter()
         # merges run as DPM processors (management plane), even when a
         # KN's blocked write path invoked them inline
-        with sanitize.management():
+        with self.telemetry.span("pool.merge"), sanitize.management():
             while self.merge_backlog and done < ops:
                 seg, _ = self.merge_backlog.popleft()
                 entries = seg.sealed_entries()
@@ -452,7 +451,6 @@ class DPMPool:
                     self._maybe_collect(seg)
         if self.merge_allowance is not None:
             self.merge_allowance -= done
-        self.merge_wall_s += time.perf_counter() - t0
         return done
 
     def merge_all(self, kn: str | None = None) -> int:
@@ -463,8 +461,7 @@ class DPMPool:
         synchronous merges must complete regardless of the async
         DPM-processor budget."""
         done = 0
-        t0 = time.perf_counter()
-        with sanitize.management():
+        with self.telemetry.span("pool.merge"), sanitize.management():
             # backlog first (order preserved), filtered by KN if given
             keep: deque = deque()
             while self.merge_backlog:
@@ -493,7 +490,6 @@ class DPMPool:
                 act.merged_upto = len(entries)
                 if entries:
                     self.segments[owner] = [self.new_segment(owner)]
-        self.merge_wall_s += time.perf_counter() - t0
         return done
 
     def merge_entries_batch(self, entries, seg: PySegment,
@@ -536,12 +532,13 @@ class DPMPool:
                 for key, ptr in entries:
                     self._merge_entry(key, ptr, seg)
                 raise KNCrash(kn, CRASH_POINTS.MERGE_POST_APPLY)
+        counts = self.telemetry.counts
         if not self.vectorized or n < MIN_MERGE_PLAN_OPS:
             for key, ptr in entries:
                 self._merge_entry(key, ptr, seg)
             if self.vectorized:       # the oracle plane never counts
-                MERGE_PLAN_STATS["replayed_windows"] += 1
-                MERGE_PLAN_STATS["replayed_entries"] += n
+                counts["merge_plan.replayed_windows"] += 1
+                counts["merge_plan.replayed_entries"] += n
             return n
         arr = np.asarray(entries, dtype=np.int64)
         keys, ptrs = arr[:, 0], arr[:, 1]
@@ -552,13 +549,13 @@ class DPMPool:
                                      indirect_keys=ind)
             if plan is None:
                 self._merge_entry(int(keys[i]), int(ptrs[i]), seg)
-                MERGE_PLAN_STATS["replayed_windows"] += 1
-                MERGE_PLAN_STATS["replayed_entries"] += 1
+                counts["merge_plan.replayed_windows"] += 1
+                counts["merge_plan.replayed_entries"] += 1
                 i += 1
                 continue
             self.apply_merge_plan(plan)
-            MERGE_PLAN_STATS["planned_windows"] += 1
-            MERGE_PLAN_STATS["planned_entries"] += plan.ops
+            counts["merge_plan.planned_windows"] += 1
+            counts["merge_plan.planned_entries"] += plan.ops
             i += plan.ops
         return n
 

@@ -54,13 +54,10 @@ on device.
 from __future__ import annotations
 
 import heapq
-import time
-from collections import Counter
 
 import numpy as np
 
 from . import sanitize
-from .transition import ENGINE_WALL
 
 _I31 = 2 ** 31 - 1
 _GUARD = 2 ** 30          # headroom for clocks/counts that grow per op
@@ -98,23 +95,33 @@ class JitEngine:
 
     def __init__(self, cluster):
         self.cluster = cluster
+        # the cluster's spans (jit.*) and counters
+        self.telemetry = cluster.telemetry
         self.resident: dict[str, _Resident] = {}
         self._vmax: dict[float, object] = {}      # amr -> device table
         self._pm_token = None                     # probe_map identity
         self._pm_ptr = self._pm_len = None
         self._pm_probes = self._pm_bucket = None
-        # where each op ran: "dispatches", "device_ops", and
-        # "host_ops.<reason>" for every op the host engine ran instead
-        # (a cut reason of batch_executor.ref.CUT_NAMES, or delete /
-        # short_span / stall / short_window / int32_guard)
-        self.counts: Counter = Counter()
-        # set-up cost of residency: host wall spent packing + uploading
-        # cache state, and the most state bytes resident at once
-        self.upload_s = 0.0
+        # the most state bytes resident at once
         self.resident_bytes_peak = 0
         # lazy import so merely constructing a cluster never pulls jax
         from ..kernels import batch_executor as be
         self.be = be
+
+    @property
+    def counts(self):
+        """The cluster's counters.  Where each op ran: "dispatches",
+        "device_ops", and "host_ops.<reason>" for every op the host
+        engine ran instead (a cut reason of batch_executor.ref.CUT_NAMES,
+        or delete / short_span / stall / short_window / int32_guard);
+        the state bytes moved: "jit.upload_bytes", "jit.sync_bytes"."""
+        return self.telemetry.counts
+
+    @property
+    def upload_s(self) -> float:
+        """Seconds in ``jit.upload`` spans (read by bench/lib/harness.py
+        under this name)."""
+        return self.telemetry.total("jit.upload")
 
     # ----- per-batch context ---------------------------------------------
     def _ensure_pm(self, probe_map, nbatch, pool) -> None:
@@ -123,19 +130,20 @@ class JitEngine:
         if self._pm_token is probe_map:
             return
         be = self.be
-        pm_ptr = np.full(nbatch, be.PM_INVALID, np.int64)
-        pm_len = np.zeros(nbatch, np.int64)
-        pm_probes = np.zeros(nbatch, np.float64)
-        pm_bucket = np.full(nbatch, -1, np.int64)
-        hl = pool.heap_len
-        for p, (pp, probes, bk) in probe_map.items():
-            if pp is None:
-                pm_ptr[p] = be.PM_ABSENT
-            else:
-                pm_ptr[p] = pp
-                pm_len[p] = hl[pp]
-            pm_probes[p] = probes
-            pm_bucket[p] = bk
+        with self.telemetry.span("jit.probe_map"):
+            pm_ptr = np.full(nbatch, be.PM_INVALID, np.int64)
+            pm_len = np.zeros(nbatch, np.int64)
+            pm_probes = np.zeros(nbatch, np.float64)
+            pm_bucket = np.full(nbatch, -1, np.int64)
+            hl = pool.heap_len
+            for p, (pp, probes, bk) in probe_map.items():
+                if pp is None:
+                    pm_ptr[p] = be.PM_ABSENT
+                else:
+                    pm_ptr[p] = pp
+                    pm_len[p] = hl[pp]
+                pm_probes[p] = probes
+                pm_bucket[p] = bk
         self._pm_ptr, self._pm_len = pm_ptr, pm_len
         self._pm_probes, self._pm_bucket = pm_probes, pm_bucket
         self._pm_token = probe_map
@@ -151,53 +159,54 @@ class JitEngine:
     def _upload(self, kn, cache, plan):
         """Pack the cache into device state; None if the int32 ranges
         (or a non-positive capacity) rule the device program out."""
-        be = self.be
-        t0 = time.perf_counter()
-        nslots = cache.kind.shape[0]
-        if not (0 < cache.capacity < _GUARD):
-            return None
-        if cache._clock >= _GUARD or nslots >= _I31:
-            return None
-        if len(self.cluster.pool.heap_val) >= _I31:
-            return None        # covers every staged/prefetched pointer
-        live = cache.kind != 0
-        if live.any():
-            if int(cache.count[live].max()) >= _GUARD:
+        with self.telemetry.span("jit.upload", kn=kn.name):
+            be = self.be
+            nslots = cache.kind.shape[0]
+            if not (0 < cache.capacity < _GUARD):
                 return None
-            if int(cache.ptr[live].max()) >= _I31:
+            if cache._clock >= _GUARD or nslots >= _I31:
                 return None
-            if int(cache.length[live].max()) >= _GUARD:
-                return None
-        # the guards above proved every value fits int32: narrow here,
-        # explicitly, so the device state never depends on an implicit
-        # int64 -> int32 conversion.  The victim trees want a
-        # power-of-two leaf count; pad with absent entries (never
-        # addressed: keys are < nslots)
-        pad = 1
-        while pad < nslots:
-            pad <<= 1
-        arrs = []
-        for a in (cache.kind, cache.count, cache.stamp, cache.length,
-                  cache.ptr):
-            a32 = np.zeros(pad, np.int32)
-            a32[:nslots] = a.astype(np.int32)
-            arrs.append(a32)
-        state = be.init_state(arrs[0], arrs[1], arrs[2], arrs[3],
-                              arrs[4], cache._cnt_hist,
-                              cache.used, cache._clock,
-                              cache._zero_shortcuts, cache._nvals,
-                              cache._nshort)
-        import jax.numpy as jnp
-        res = _Resident()
-        res.cache = cache
-        res.kn_name = kn.name
-        res.kind0 = state[0]                  # host int32 shadow
-        res.state = tuple(jnp.asarray(a) for a in state)
-        res.nslots = nslots
-        res.demo0 = 0
-        res.evic0 = 0
-        self.upload_s += time.perf_counter() - t0
-        held = sum(a.nbytes for a in state) + sum(
+            if len(self.cluster.pool.heap_val) >= _I31:
+                return None    # covers every staged/prefetched pointer
+            live = cache.kind != 0
+            if live.any():
+                if int(cache.count[live].max()) >= _GUARD:
+                    return None
+                if int(cache.ptr[live].max()) >= _I31:
+                    return None
+                if int(cache.length[live].max()) >= _GUARD:
+                    return None
+            # the guards above proved every value fits int32: narrow
+            # here, explicitly, so the device state never depends on an
+            # implicit int64 -> int32 conversion.  The victim trees want
+            # a power-of-two leaf count; pad with absent entries (never
+            # addressed: keys are < nslots)
+            pad = 1
+            while pad < nslots:
+                pad <<= 1
+            arrs = []
+            for a in (cache.kind, cache.count, cache.stamp, cache.length,
+                      cache.ptr):
+                a32 = np.zeros(pad, np.int32)
+                a32[:nslots] = a.astype(np.int32)
+                arrs.append(a32)
+            state = be.init_state(arrs[0], arrs[1], arrs[2], arrs[3],
+                                  arrs[4], cache._cnt_hist,
+                                  cache.used, cache._clock,
+                                  cache._zero_shortcuts, cache._nvals,
+                                  cache._nshort)
+            import jax.numpy as jnp
+            res = _Resident()
+            res.cache = cache
+            res.kn_name = kn.name
+            res.kind0 = state[0]                  # host int32 shadow
+            res.state = tuple(jnp.asarray(a) for a in state)
+            res.nslots = nslots
+            res.demo0 = 0
+            res.evic0 = 0
+        nbytes = sum(a.nbytes for a in state)
+        self.telemetry.counts["jit.upload_bytes"] += nbytes
+        held = nbytes + sum(
             a.nbytes for r in self.resident.values() for a in r.state)
         self.resident_bytes_peak = max(self.resident_bytes_peak, held)
         return res
@@ -209,37 +218,39 @@ class JitEngine:
         res = self.resident.pop(name, None)
         if res is None:
             return
-        t0 = time.perf_counter()
-        be = self.be
-        kind, count, stamp, length, ptr, _wrote, hist, regs = \
-            (np.asarray(a) for a in res.state)
-        cache = res.cache
-        ns = res.nslots
-        with sanitize.owned(res.kn_name):
-            # device arrays are padded to a power of two; only the
-            # first ns slots are real (pad entries are never addressed)
-            cache.kind[:ns] = kind[:ns].astype(np.int8)
-            cache.count[:ns] = count[:ns]
-            cache.stamp[:ns] = stamp[:ns]
-            cache.length[:ns] = length[:ns]
-            cache.ptr[:ns] = ptr[:ns]
-        cache._cnt_hist[:] = hist.tolist()
-        cache.used = int(regs[be.R_USED])
-        cache._clock = int(regs[be.R_CLOCK])
-        cache._zero_shortcuts = int(regs[be.R_ZSHORT])
-        cache._nvals = int(regs[be.R_NVALS])
-        cache._nshort = int(regs[be.R_NSHORT])
-        # entries whose kind survived keep their lazy-heap records
-        # (stale stamps/counts self-heal on pop); changed kinds need
-        # one fresh record to stay visible to victim selection
-        lru, lfu = cache._lru, cache._lfu
-        for k in np.nonzero(kind != res.kind0)[0].tolist():
-            kd = int(kind[k])
-            if kd == 2:
-                heapq.heappush(lru, (int(stamp[k]), k))
-            elif kd == 1:
-                heapq.heappush(lfu, (int(count[k]), k))
-        ENGINE_WALL["jit_sync"] += time.perf_counter() - t0
+        with self.telemetry.span("jit.sync", kn=name):
+            be = self.be
+            host = [np.asarray(a) for a in res.state]
+            self.telemetry.counts["jit.sync_bytes"] += sum(
+                a.nbytes for a in host)
+            kind, count, stamp, length, ptr, _wrote, hist, regs = host
+            cache = res.cache
+            ns = res.nslots
+            with sanitize.owned(res.kn_name):
+                # device arrays are padded to a power of two; only the
+                # first ns slots are real (pad entries are never
+                # addressed)
+                cache.kind[:ns] = kind[:ns].astype(np.int8)
+                cache.count[:ns] = count[:ns]
+                cache.stamp[:ns] = stamp[:ns]
+                cache.length[:ns] = length[:ns]
+                cache.ptr[:ns] = ptr[:ns]
+            cache._cnt_hist[:] = hist.tolist()
+            cache.used = int(regs[be.R_USED])
+            cache._clock = int(regs[be.R_CLOCK])
+            cache._zero_shortcuts = int(regs[be.R_ZSHORT])
+            cache._nvals = int(regs[be.R_NVALS])
+            cache._nshort = int(regs[be.R_NSHORT])
+            # entries whose kind survived keep their lazy-heap records
+            # (stale stamps/counts self-heal on pop); changed kinds need
+            # one fresh record to stay visible to victim selection
+            lru, lfu = cache._lru, cache._lfu
+            for k in np.nonzero(kind != res.kind0)[0].tolist():
+                kd = int(kind[k])
+                if kd == 2:
+                    heapq.heappush(lru, (int(stamp[k]), k))
+                elif kd == 1:
+                    heapq.heappush(lfu, (int(count[k]), k))
 
     def sync_all(self) -> None:
         for name in list(self.resident):
@@ -354,59 +365,58 @@ class JitEngine:
     def _dispatch(self, kn, cache, res, full, skeys, sops, lo, n, plan,
                   dkeys, dbuckets, out_values):
         be = self.be
-        t0 = time.perf_counter()
-        hi = lo + n
-        spos = full[lo:hi]
-        ck = skeys[lo:hi]
-        co = sops[lo:hi]
-        wpad = _bucket(n)
-        ops32 = np.zeros(wpad, np.int32)
-        keys32 = np.zeros(wpad, np.int32)
-        wptr32 = np.zeros(wpad, np.int32)
-        pmp = np.full(wpad, be.PM_INVALID, np.int64)
-        pml = np.zeros(wpad, np.int32)
-        seg0 = np.zeros(wpad, np.int32)
-        ops32[:n] = co                         # deletes were clamped out
-        keys32[:n] = ck
-        if plan.nw:
-            wr = plan.wrank[spos]
-            wptr32[:n] = plan.ptrs[np.maximum(wr, 0)]
-        pmp[:n] = self._pm_ptr[spos]
-        pml[:n] = self._pm_len[spos]
-        # a prefetch stays valid only while its key and bucket are
-        # untouched by mid-batch merges (the pool's dirty sets)
-        if dkeys:
-            dk = np.fromiter(dkeys, np.int64, len(dkeys))
-            pmp[:n][np.isin(ck, dk)] = be.PM_INVALID
-        if dbuckets:
-            db = np.fromiter(dbuckets, np.int64, len(dbuckets))
-            pmp[:n][np.isin(self._pm_bucket[spos], db)] = be.PM_INVALID
-        segd = kn.segcache
-        if segd:
-            sk = np.fromiter(segd.keys(), np.int64, len(segd))
-            seg0[:n] = np.isin(ck, sk)
-        vmax = self._vmax_for(cache)
-        ENGINE_WALL["jit_prep"] += time.perf_counter() - t0
+        tel = self.telemetry
+        name = kn.name
+        with tel.span("jit.prep", kn=name):
+            hi = lo + n
+            spos = full[lo:hi]
+            ck = skeys[lo:hi]
+            co = sops[lo:hi]
+            wpad = _bucket(n)
+            ops32 = np.zeros(wpad, np.int32)
+            keys32 = np.zeros(wpad, np.int32)
+            wptr32 = np.zeros(wpad, np.int32)
+            pmp = np.full(wpad, be.PM_INVALID, np.int64)
+            pml = np.zeros(wpad, np.int32)
+            seg0 = np.zeros(wpad, np.int32)
+            ops32[:n] = co                         # deletes were clamped out
+            keys32[:n] = ck
+            if plan.nw:
+                wr = plan.wrank[spos]
+                wptr32[:n] = plan.ptrs[np.maximum(wr, 0)]
+            pmp[:n] = self._pm_ptr[spos]
+            pml[:n] = self._pm_len[spos]
+            # a prefetch stays valid only while its key and bucket are
+            # untouched by mid-batch merges (the pool's dirty sets)
+            if dkeys:
+                dk = np.fromiter(dkeys, np.int64, len(dkeys))
+                pmp[:n][np.isin(ck, dk)] = be.PM_INVALID
+            if dbuckets:
+                db = np.fromiter(dbuckets, np.int64, len(dbuckets))
+                pmp[:n][np.isin(self._pm_bucket[spos], db)] = be.PM_INVALID
+            segd = kn.segcache
+            if segd:
+                sk = np.fromiter(segd.keys(), np.int64, len(segd))
+                seg0[:n] = np.isin(ck, sk)
+            vmax = self._vmax_for(cache)
 
-        t0 = time.perf_counter()
-        out = be.fused_window(res.state, ops32, keys32, wptr32,
-                              pmp.astype(np.int32), pml, seg0, n,
-                              cache.capacity, self.cluster.value_bytes,
-                              vmax)
-        ne = int(out[0])
-        res.state = out[1]               # the passed state was donated
-        self.counts["dispatches"] += 1
-        self.counts["device_ops"] += ne
-        events = np.asarray(out[2])[:ne]
-        out_ptr = np.asarray(out[3])[:ne]
-        cut = int(out[4])
-        regs = np.asarray(out[1][7])
-        ENGINE_WALL["jit_dispatch"] += time.perf_counter() - t0
+        with tel.span("jit.dispatch", kn=name):
+            out = be.fused_window(res.state, ops32, keys32, wptr32,
+                                  pmp.astype(np.int32), pml, seg0, n,
+                                  cache.capacity, self.cluster.value_bytes,
+                                  vmax)
+            ne = int(out[0])
+            res.state = out[1]           # the passed state was donated
+            tel.counts["dispatches"] += 1
+            tel.counts["device_ops"] += ne
+            events = np.asarray(out[2])[:ne]
+            out_ptr = np.asarray(out[3])[:ne]
+            cut = int(out[4])
+            regs = np.asarray(out[1][7])
 
-        t0 = time.perf_counter()
-        self._fold(kn, cache, res, spos[:ne], ck[:ne], events, out_ptr,
-                   regs, plan, out_values)
-        ENGINE_WALL["jit_fold"] += time.perf_counter() - t0
+        with tel.span("jit.fold", kn=name):
+            self._fold(kn, cache, res, spos[:ne], ck[:ne], events, out_ptr,
+                       regs, plan, out_values)
         return ne, cut
 
     def _fold(self, kn, cache, res, ps, ks, ev, out_ptr, regs, plan,

@@ -38,7 +38,7 @@ def test_smoke_phases_agree_at_tiny_size(capsys):
     """Every phase after the device check, on this CPU: the jit and
     host twins agree and the compiled executor ran ops."""
     chip = chip_smoke.run(keys=4096, batches=1, seed=1)
-    assert chip._jit.counts["device_ops"] > 0
+    assert chip.telemetry.counts["device_ops"] > 0
     out = capsys.readouterr().out
     assert "reduced: keys 2^25 -> 2^12" in out
     for mix in chip_smoke.SMOKE_MIXES:

@@ -328,17 +328,16 @@ class TestJitEngineEquivalence:
         compiled engine genuinely dispatches device windows AND hands
         truncation residuals to host replay -- the equivalence sweep
         above cannot rot into an always-replay identity."""
-        from repro.core.transition import ENGINE_WALL, reset_engine_wall
         a, b = build_jit_pair(3, 1 << 15)
         w = Workload(num_keys=6000, zipf=1.2, mix="write_heavy_update",
                      seed=3)
         kinds, keys = w.ops_arrays(6000)
         a.execute_batch(kinds, keys, values=lambda i: f"w{i}")
-        reset_engine_wall()
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}",
                         engine="jit")
-        assert ENGINE_WALL["jit_dispatch"] > 0
-        assert ENGINE_WALL["host_replay"] > 0
+        assert b.telemetry.total("jit.dispatch") > 0
+        assert b.telemetry.total("host.replay") > 0
+        assert a.telemetry.total("jit.dispatch") == 0
         assert cluster_snapshot(a) == cluster_snapshot(b)
         assert a.aggregate_stats() == b.aggregate_stats()
 

@@ -18,7 +18,8 @@ The generators here are adversarial by construction:
   * tiny merge allowances force budget exhaustion mid-plan;
   * tiny segments force mid-batch seals (rotations) between plans.
 
-Coverage is asserted (MERGE_PLAN_STATS) so the planned path cannot rot
+Coverage is asserted (the ``merge_plan.*`` counts of the pool's
+telemetry) so the planned path cannot rot
 into dead code behind its scalar replay fallback.
 """
 
@@ -30,9 +31,7 @@ from hypothesis import strategies as st
 from repro.core import DinomoCluster, VARIANTS
 from repro.core.clht import NumpyCLHT
 from repro.core.dpm_pool import DPMPool
-from repro.core.transition import (MERGE_PLAN_STATS, MIN_MERGE_PLAN_OPS,
-                                   plan_merge_window,
-                                   reset_merge_plan_stats)
+from repro.core.transition import MIN_MERGE_PLAN_OPS, plan_merge_window
 from repro.data import Workload
 
 
@@ -165,10 +164,10 @@ class TestPlannedInsertEquivalence:
         t = NumpyCLHT(1 << 12)
         rng = np.random.default_rng(0)
         keys, ptrs = adversarial_entries(rng, 512, 4000)
-        reset_merge_plan_stats()
         t.insert_batch(keys, ptrs)
-        assert MERGE_PLAN_STATS["planned_entries"] == 512
-        assert MERGE_PLAN_STATS["replayed_entries"] == 0
+        counts = t.telemetry.counts
+        assert counts["merge_plan.planned_entries"] == 512
+        assert counts["merge_plan.replayed_entries"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -267,16 +266,17 @@ class TestPlannedMergeEquivalence:
         pool.register_kn("kn1")
         rng = np.random.default_rng(0)
         keys = (rng.zipf(1.5, 12000) % 100000).astype(np.int64)
-        reset_merge_plan_stats()
+        pool.telemetry.reset()
         for i, k in enumerate(keys.tolist()):
             pool.log_write("kn1", k, f"w{i}", 64)
             if i % 997 == 0:
                 pool.merge_budget(512)
         pool.merge_all()
-        tot = (MERGE_PLAN_STATS["planned_entries"]
-               + MERGE_PLAN_STATS["replayed_entries"])
+        counts = pool.telemetry.counts
+        tot = (counts["merge_plan.planned_entries"]
+               + counts["merge_plan.replayed_entries"])
         assert tot >= 12000
-        assert MERGE_PLAN_STATS["planned_entries"] / tot >= 0.95
+        assert counts["merge_plan.planned_entries"] / tot >= 0.95
 
     def test_truncated_plan_never_double_charges(self):
         """Satellite regression (allowance accounting): a window whose
@@ -309,16 +309,18 @@ class TestPlannedMergeEquivalence:
             k = int(rng.integers(0, 4000))
             a.log_write("kn1", k, f"w{i}", 64)
             b.log_write("kn1", k, f"w{i}", 64)
-        reset_merge_plan_stats()
         for p in (a, b):
+            p.telemetry.reset()
             p.merge_allowance = 100
         assert a.merge_budget(10**6) == 100
         assert b.merge_budget(10**6) == 100
         assert pool_state(a) == pool_state(b)
         assert_tables_equal(a.index, b.index)
-        # the planned plane covered the clamped window in plans alone
-        assert MERGE_PLAN_STATS["planned_entries"] == 100
-        assert MERGE_PLAN_STATS["replayed_entries"] == 0
+        # the planned plane covered the clamped window in plans alone;
+        # the oracle plane never counts
+        assert not a.telemetry.counts
+        assert b.telemetry.counts["merge_plan.planned_entries"] == 100
+        assert b.telemetry.counts["merge_plan.replayed_entries"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -370,18 +372,19 @@ class TestClusterMergePlane:
                       mix="write_heavy_update", seed=seed % 101)
         w2 = Workload(num_keys=4000, zipf=1.2,
                       mix="write_heavy_update", seed=seed % 101)
-        reset_merge_plan_stats()
+        for c in (a, b):
+            c.telemetry.reset()
         for i, (kind, key) in enumerate(w1.ops(2000)):
             if kind == "read":
                 a.read(key)
             else:
                 a.write(key, f"w{i}")
-        planned_scalar = MERGE_PLAN_STATS["planned_entries"]
-        assert planned_scalar > 0        # per-op stalls plan too
+        # per-op stalls plan too
+        assert a.telemetry.counts["merge_plan.planned_entries"] > 0
         kinds, keys = w2.ops_arrays(2000)
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}")
         assert cluster_snapshot(a) == cluster_snapshot(b)
-        assert MERGE_PLAN_STATS["planned_entries"] > planned_scalar
+        assert b.telemetry.counts["merge_plan.planned_entries"] > 0
         assert sum(kn.stats.write_stalls for kn in b.kns.values()) > 0
 
     def test_contested_index_cluster(self):
@@ -395,7 +398,7 @@ class TestClusterMergePlane:
                       mix="write_heavy_insert", seed=3)
         w2 = Workload(num_keys=600, zipf=1.0,
                       mix="write_heavy_insert", seed=3)
-        reset_merge_plan_stats()
+        b.telemetry.reset()
         for i, (kind, key) in enumerate(w1.ops(1500)):
             if kind == "read":
                 a.read(key)
@@ -405,8 +408,8 @@ class TestClusterMergePlane:
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}")
         assert cluster_snapshot(a) == cluster_snapshot(b)
         # adversarial coverage: both planned and replayed paths engaged
-        assert MERGE_PLAN_STATS["planned_entries"] > 0
-        assert MERGE_PLAN_STATS["replayed_entries"] > 0
+        assert b.telemetry.counts["merge_plan.planned_entries"] > 0
+        assert b.telemetry.counts["merge_plan.replayed_entries"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -465,14 +468,16 @@ class TestJitClusterMergePlane:
         w = Workload(num_keys=4000, zipf=1.2,
                      mix="write_heavy_update", seed=seed % 101)
         kinds, keys = w.ops_arrays(2000)
-        reset_merge_plan_stats()
+        for c in (a, b):
+            c.telemetry.reset()
         a.execute_batch(kinds, keys, values=lambda i: f"w{i}")
-        planned_host = MERGE_PLAN_STATS["planned_entries"]
+        planned_host = a.telemetry.counts["merge_plan.planned_entries"]
         assert planned_host > 0
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}",
                         engine="jit")
         assert cluster_snapshot(a) == cluster_snapshot(b)
-        assert MERGE_PLAN_STATS["planned_entries"] == 2 * planned_host
+        assert b.telemetry.counts["merge_plan.planned_entries"] \
+            == planned_host
         assert sum(kn.stats.write_stalls for kn in b.kns.values()) > 0
 
     def test_contested_index_jit(self):
@@ -486,12 +491,12 @@ class TestJitClusterMergePlane:
                       mix="write_heavy_insert", seed=3)
         w2 = Workload(num_keys=600, zipf=1.0,
                       mix="write_heavy_insert", seed=3)
-        reset_merge_plan_stats()
+        b.telemetry.reset()
         kinds, keys = w1.ops_arrays(1500)
         a.execute_batch(kinds, keys, values=lambda i: f"w{i}")
         kinds, keys = w2.ops_arrays(1500)
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}",
                         engine="jit")
         assert cluster_snapshot(a) == cluster_snapshot(b)
-        assert MERGE_PLAN_STATS["planned_entries"] > 0
-        assert MERGE_PLAN_STATS["replayed_entries"] > 0
+        assert b.telemetry.counts["merge_plan.planned_entries"] > 0
+        assert b.telemetry.counts["merge_plan.replayed_entries"] > 0

@@ -457,7 +457,6 @@ class TestWritePlaneEquivalence:
 # PR 3: the planned-transition engine (core.transition)
 # ---------------------------------------------------------------------------
 from repro.core.dac import CNT_HIST_MAX, ArrayDAC, DAC
-from repro.core.transition import PLAN_STATS, reset_plan_stats
 
 
 class TestPlannedEngine:
@@ -475,19 +474,19 @@ class TestPlannedEngine:
         a, b = build_pair(variant, seed % 3, 1 << 19, num_keys=6000,
                           segment_capacity=256)
         kinds, keys = mixed_ops(seed, 6000, 4000, mix, delete_frac=0.05)
-        reset_plan_stats()
         apply_scalar(a, kinds, keys)
         b.execute_batch(kinds, keys, values=lambda i: f"w{i}")
         assert cluster_snapshot(a) == cluster_snapshot(b)
         if variant != "clover":
-            total = PLAN_STATS["planned_ops"] + PLAN_STATS["replayed_ops"]
+            plan = b.telemetry.counts
+            total = plan["plan.planned_ops"] + plan["plan.replayed_ops"]
             assert total > 0
-            assert PLAN_STATS["planned_ops"] > 0
+            assert plan["plan.planned_ops"] > 0
             if mix.startswith("write_heavy"):
                 # steady-state write windows must plan, not replay
                 # (read-mostly windows may route to the bulk-hit path,
                 # which is counted as replay)
-                assert PLAN_STATS["planned_ops"] > total // 2
+                assert plan["plan.planned_ops"] > total // 2
 
     @given(st.integers(0, 10**6))
     @settings(max_examples=6, deadline=None)
