@@ -15,18 +15,31 @@ comparison is discretized into the host-built promote threshold table,
 see ``ref.build_promote_table``), so the compiled path cannot drift
 from the reference by a rounding flip.
 
-Three lowering choices are load-bearing for CPU/interpret performance
-(each verified against the compiled HLO; getting any one wrong
-regresses a window from O(ops x log slots) to O(ops x slots) memory
-traffic):
+Four lowering choices keep each op to a few small launches; getting
+one wrong can turn them into whole-array memory traffic or multiply
+them.  ``tests/test_chip_compile.py`` checks the result on the program
+compiled for a TPU v5e at the served slot count: bounded fusions per
+op and per make-space iteration, no conditional and no whole-array
+copy or scatter in either loop, no large gather at entry.
 
 * Victim selection.  The reference's lazy LRU/LFU heaps become two
-  tournament min-trees over (value, key) -- LRU over value-entry
-  stamps, LFU over shortcut counts, absent entries at +inf -- built
-  vectorized at dispatch entry (O(n)) and maintained with O(log n)
-  leaf updates as ops mutate entries.  The root is exactly what the
-  lazy heaps pop: argmin (stamp, key) / argmin (count, key) over live
-  entries.  A flat argmin would be O(n) *per eviction*.
+  min-trees over (value, key) -- LRU over value-entry stamps, LFU over
+  shortcut counts, absent entries at +inf -- built vectorized at
+  dispatch entry (O(n)) and kept with O(sqrt n) leaf updates as ops
+  mutate entries.  The root is exactly what the lazy heaps pop: argmin
+  (stamp, key) / argmin (count, key) over live entries.  A flat argmin
+  would be O(n) *per eviction*.  Each tree has two levels: the leaves,
+  and the minima of contiguous blocks of ~sqrt(n) leaves, so the build
+  is one reduce over a reshape (no strided pairing, which lowers to
+  gathers at ~36 M elements/s on the chip), and a leaf update is the
+  same few launches at any slot count: an in-place write per leaf
+  lane, one reduce over the leaf's block, an in-place write per block
+  lane.  A binary tree costs a launch per level and lane as
+  per-level arrays, and a pass over the whole lane as one flat array
+  written by a scatter (a TPU scatter reads and writes its whole
+  operand).  Lanes stay separate 1-D arrays: a ``(n, lanes)`` layout
+  would be tiled ``(8, 128)`` on the TPU, its minor dimension padded
+  32x.
 
 * Predication, not branching.  The per-op state machine is a
   straight-line body of masked scalar scatters.  ``lax.cond`` /
@@ -44,6 +57,10 @@ traffic):
   while loop -- demote strictly while values remain, then evict --
   which matches the reference's two sequential loops and halves the
   nested-boundary crossings.
+
+* Hits read the post-update ``ptr`` (see the body): re-reading the
+  pre-update array would anti-depend on its in-place write, which can
+  cost a whole-array copy per op.
 
 This is a pure ``jax.jit``/``lax`` program (no Pallas), so it needs no
 interpret-mode resolution.  Slot count must be a power of two (the
@@ -76,68 +93,57 @@ def _i32(b):
     return b.astype(jnp.int32)
 
 
-# ----- tournament min-trees over (value, key, *payloads) -----------------
-# Level-0 keys are the identity (leaf i holds key i), so they are never
-# materialized: a carried constant arange would cost XLA a full-array
-# copy per loop iteration.  Key arrays start at level 1.
+# ----- two-level min-trees over (value, key, *payloads) ------------------
+# A tree is (leaf lanes, block lanes).  Leaf lanes are (value,
+# *payloads), one 1-D array of n entries each; leaf i holds key i, never
+# materialized (a carried constant arange would cost XLA a full-array
+# copy per loop iteration).  The leaves split into contiguous blocks of
+# f = 2^ceil(log2(n)/2); block lanes are (value, key, *payloads) of each
+# block's (value, key) minimum, n/f entries each.  The root is the
+# minimum of the blocks, taken when asked for.
+def _lexmin(lanes, axis):
+    """(value, key, *payloads) of the (value, key) minimum along
+    ``axis``: one variadic reduce."""
+    def pick(a, b):
+        tb = (b[0] < a[0]) | ((b[0] == a[0]) & (b[1] < a[1]))
+        return tuple(jnp.where(tb, y, x) for x, y in zip(a, b))
+
+    init = (_BIG, _BIG) + (jnp.int32(0),) * (len(lanes) - 2)
+    return lax.reduce(tuple(lanes), init, pick, (axis,))
+
+
 def _tree_build(vals, payloads=()):
-    """Bottom-up (value, key) min-tree: level 0 = leaves, last level =
-    the root.  ``payloads`` are extra leaf lanes carried up with each
-    subtree's winner.  Returns (vals 0..d, keys 1..d, *lanes 0..d)."""
-    a, b = vals[0::2], vals[1::2]
-    tb = b < a                       # leaf keys: even index wins ties
-    lv = [vals, jnp.where(tb, b, a)]
-    base = jnp.arange(vals.shape[0] // 2, dtype=jnp.int32) * 2
-    lk = [base + _i32(tb)]
-    lp = [[p, jnp.where(tb, p[1::2], p[0::2])] for p in payloads]
-    while lv[-1].shape[0] > 1:
-        a, b = lv[-1][0::2], lv[-1][1::2]
-        ak, bk = lk[-1][0::2], lk[-1][1::2]
-        tb = (b < a) | ((b == a) & (bk < ak))
-        lv.append(jnp.where(tb, b, a))
-        lk.append(jnp.where(tb, bk, ak))
-        for lanes in lp:
-            lanes.append(jnp.where(tb, lanes[-1][1::2],
-                                   lanes[-1][0::2]))
-    return (tuple(lv), tuple(lk)) + tuple(tuple(l) for l in lp)
+    """(value, key) min-tree over leaves ``vals``; ``payloads`` are
+    extra leaf lanes carried with each block's winner."""
+    n = vals.shape[0]
+    f = 1 << (n.bit_length() // 2)   # n = 2^d -> f = 2^ceil(d/2)
+    leaves = (vals,) + tuple(payloads)
+    keys = jnp.arange(n, dtype=jnp.int32)
+    blocks = _lexmin([l.reshape(n // f, f) for l in (vals, keys)
+                      + tuple(payloads)], 1)
+    return leaves, blocks
 
 
 def _tree_set(tree, k, val, pvals=()):
-    """Set leaf k to val (+ payloads) and re-min the root path
-    (O(log n))."""
-    lv, lk = tree[0], tree[1]
-    lp = tree[2:]
-    ov = [lv[0].at[k].set(val)]
-    ok = []
-    op = [[lanes[0].at[k].set(pv)] for lanes, pv in zip(lp, pvals)]
-    idx = k >> 1
-    left = idx * 2
-    a, b = ov[0][left], ov[0][left + 1]
-    tb = b < a
-    ov.append(lv[1].at[idx].set(jnp.where(tb, b, a)))
-    ok.append(lk[0].at[idx].set(left + _i32(tb)))
-    for lanes, built in zip(lp, op):
-        built.append(lanes[1].at[idx].set(
-            jnp.where(tb, built[0][left + 1], built[0][left])))
-    for j in range(2, len(lv)):
-        idx = idx >> 1
-        left = idx * 2
-        a, b = ov[j - 1][left], ov[j - 1][left + 1]
-        ak, bk = ok[j - 2][left], ok[j - 2][left + 1]
-        tb = (b < a) | ((b == a) & (bk < ak))
-        ov.append(lv[j].at[idx].set(jnp.where(tb, b, a)))
-        ok.append(lk[j - 1].at[idx].set(jnp.where(tb, bk, ak)))
-        for lanes, built in zip(lp, op):
-            built.append(lanes[j].at[idx].set(
-                jnp.where(tb, built[j - 1][left + 1],
-                          built[j - 1][left])))
-    return (tuple(ov), tuple(ok)) + tuple(tuple(b) for b in op)
+    """Set leaf k to val (+ payloads) and re-min its block: one
+    in-place write per leaf lane, one reduce over the block's f
+    contiguous leaves, one in-place write per block lane -- whatever
+    the slot count.  (A scatter of several entries is no substitute:
+    on the TPU it passes over its whole operand.)"""
+    leaves, blocks = tree
+    f = leaves[0].shape[0] // blocks[0].shape[0]
+    leaves = tuple(l.at[k].set(x) for l, x in zip(leaves, (val,)
+                                                  + tuple(pvals)))
+    b = k // f
+    blk = [lax.dynamic_slice(l, (b * f,), (f,)) for l in leaves]
+    keys = b * f + jnp.arange(f, dtype=jnp.int32)
+    best = _lexmin([blk[0], keys] + blk[1:], 0)
+    return leaves, tuple(l.at[b].set(x) for l, x in zip(blocks, best))
 
 
 def _tree_min(tree):
     """(min value, its key, *payloads) -- the lazy-heap pop order."""
-    return (tree[0][-1][0], tree[1][-1][0]) + tuple(
-        lanes[-1][0] for lanes in tree[2:])
+    return _lexmin(tree[1], 0)
 
 
 def _lru_set(tr, k, val, ln, cnt):

@@ -16,6 +16,7 @@ where the TPU compiler (libtpu) is not installed.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -76,9 +77,11 @@ def _fits(compiled):
     return m
 
 
-def test_fused_window_compiles_at_smoke_slots(one_chip):
+@pytest.fixture(scope="module")
+def smoke_window(one_chip):
     """The served executor at the smoke's slot count (2^22 keys -> a
-    2^22-slot cache per KN) and W_MAX ops, with the state donated."""
+    2^22-slot cache per KN) and W_MAX ops, with the state donated;
+    returns (slot count, compiled program)."""
     sys.path.insert(0, REPO)
     import chip_smoke
     S = _spec(one_chip)
@@ -88,9 +91,92 @@ def test_fused_window_compiles_at_smoke_slots(one_chip):
     w = S((W_MAX,))
     lowered = compiled_window("tpu").lower(
         state, w, w, w, w, w, w, S(()), S(()), S(()), S((be.TABLE_N,)))
-    m = _fits(lowered.compile())
+    return nslots, lowered.compile()
+
+
+def test_fused_window_compiles_at_smoke_slots(smoke_window):
+    nslots, compiled = smoke_window
+    m = _fits(compiled)
     # donation took: the returned state reuses the passed buffers
     assert m.alias_size_in_bytes >= 6 * 4 * nslots, m
+
+
+def _computations(hlo):
+    """{name: instruction lines} of an HLO module's text, and the
+    entry computation's name."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _while_bodies(lines):
+    return [re.search(r"body=%?([\w.\-]+)", l).group(1)
+            for l in lines if " while(" in l]
+
+
+def _elements(line):
+    """Element count of an instruction's result (a tuple's first)."""
+    shape = re.search(r"= \(?\w+\[([\d,]*)\]", line)
+    n = 1
+    for d in (shape.group(1).split(",") if shape else ["0"]):
+        n *= int(d) if d else 1
+    return n
+
+
+def _ops(lines, op):
+    return [l for l in lines if re.search(r"\b%s\(" % op, l)]
+
+
+def _reached(comps, name):
+    """``name`` and every computation it calls, except loop bodies and
+    conditions (their own instructions are counted apart)."""
+    seen, todo = set(), [name]
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            for l in comps[name]:
+                todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", l)
+    return [l for name in seen for l in comps[name]]
+
+
+def test_fused_window_structure_on_tpu(smoke_window):
+    """The TPU-compiled executor keeps the victim trees' costs flat in
+    the slot count: each op's loop body and each make-space iteration
+    launch a bounded number of fusions; neither body scatters into or
+    copies a whole state or tree array (a TPU scatter passes over its
+    whole operand; a copy would come from a branch, from make-space
+    reading what it does not write, or from hits re-reading the
+    pre-update ptr); the entry builds the trees from contiguous blocks,
+    with no large gather."""
+    _, compiled = smoke_window
+    comps, entry = _computations(compiled.as_text())
+    outer = [b for b in _while_bodies(comps[entry])
+             if _while_bodies(comps[b])]
+    assert len(outer) == 1, _while_bodies(comps[entry])
+    inner = _while_bodies(comps[outer[0]])
+    assert len(inner) == 1, inner
+    big = 1 << 20
+    for body, most in ((outer[0], 120), (inner[0], 60)):
+        lines = comps[body]
+        assert len(_ops(lines, "fusion")) <= most, body
+        assert not _ops(lines, "conditional"), body
+        assert not [l for l in _ops(lines, "copy(-start)?")
+                    if _elements(l) >= big], body
+        assert not [l for l in _ops(_reached(comps, body), "scatter")
+                    if _elements(l) >= big], body
+    gathers = [l for l in _ops(_reached(comps, entry), "gather")
+               if _elements(l) >= big]
+    assert not gathers, gathers
 
 
 def _tables(S):

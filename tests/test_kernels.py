@@ -433,6 +433,8 @@ def test_default_interpret_kernel_matches_oracle():
 # ---------------------------------------------------------------- #
 
 from repro.kernels import batch_executor as be  # noqa: E402
+from repro.kernels.batch_executor.ops import (_BIG, _tree_build,  # noqa: E402
+                                              _tree_min, _tree_set)
 
 
 def _be_run_chain(seed, nslots=32, w=64, windows=3):
@@ -484,6 +486,50 @@ def test_batch_executor_matches_oracle_chain(seed):
     evictions, demotions, window cuts) agree bit-for-bit with the
     numpy oracle -- the fused engine's per-op contract."""
     _be_run_chain(seed)
+
+
+@pytest.mark.parametrize("nslots", [2, 32, 1024])
+@pytest.mark.parametrize("seed", [100, 101])
+def test_batch_executor_matches_oracle_chain_slots(seed, nslots):
+    """The same contract from a two-slot cache (every op collides)
+    to 1024 slots, where each victim tree splits into 32 blocks."""
+    _be_run_chain(seed, nslots=nslots, windows=4)
+
+
+@pytest.mark.parametrize("nslots", [2, 4, 1024])
+def test_victim_tree_root_is_lexicographic_argmin(nslots):
+    """After every random leaf update (values from a tiny set, so
+    nearly every comparison is a tie broken by key) the root is the
+    numpy argmin of (value, key) with that leaf's payloads, and the
+    incrementally kept tree equals one built from scratch."""
+    rng = np.random.default_rng(nslots)
+    pool = np.array([0, 1, 2, int(_BIG)], np.int32)
+    vals = rng.choice(pool, nslots).astype(np.int32)
+    pay = rng.integers(0, 1000, (2, nslots)).astype(np.int32)
+    n_up = 200
+    ks = rng.integers(0, nslots, n_up).astype(np.int32)
+    vs = rng.choice(pool, n_up).astype(np.int32)
+    ps = rng.integers(0, 1000, (n_up, 2)).astype(np.int32)
+
+    @jax.jit
+    def run(vals, pay, ks, vs, ps):
+        def step(tree, x):
+            tree = _tree_set(tree, x[0], x[1], (x[2], x[3]))
+            return tree, jnp.stack(_tree_min(tree))
+        tree = _tree_build(vals, payloads=(pay[0], pay[1]))
+        return jax.lax.scan(step, tree, (ks, vs, ps[:, 0], ps[:, 1]))
+
+    tree, roots = run(vals, pay, ks, vs, ps)
+    roots = np.asarray(roots)
+    for t in range(n_up):
+        vals[ks[t]] = vs[t]
+        pay[:, ks[t]] = ps[t]
+        win = np.lexsort((np.arange(nslots), vals))[0]
+        np.testing.assert_array_equal(
+            roots[t], [vals[win], win, pay[0, win], pay[1, win]])
+    rebuilt = _tree_build(jnp.asarray(vals), payloads=tuple(pay))
+    for got, want in zip(jax.tree.leaves(tree), jax.tree.leaves(rebuilt)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_batch_executor_truncation_residual():
