@@ -87,7 +87,7 @@ class _Resident:
     """One KN's device-resident cache state within a batch."""
 
     __slots__ = ("cache", "kn_name", "state", "nslots", "kind0",
-                 "demo0", "evic0")
+                 "demo0", "evic0", "ms0")
 
 
 class JitEngine:
@@ -114,7 +114,9 @@ class JitEngine:
         "device_ops", and "host_ops.<reason>" for every op the host
         engine ran instead (a cut reason of batch_executor.ref.CUT_NAMES,
         or delete / short_span / stall / short_window / int32_guard);
-        the state bytes moved: "jit.upload_bytes", "jit.sync_bytes"."""
+        the state bytes moved: "jit.upload_bytes", "jit.sync_bytes";
+        "jit.make_space_ops", the device ops whose make-space demoted or
+        evicted at least one entry."""
         return self.telemetry.counts
 
     @property
@@ -204,6 +206,7 @@ class JitEngine:
             res.nslots = nslots
             res.demo0 = 0
             res.evic0 = 0
+            res.ms0 = 0
         nbytes = sum(a.nbytes for a in state)
         self.telemetry.counts["jit.upload_bytes"] += nbytes
         held = nbytes + sum(
@@ -447,6 +450,8 @@ class JitEngine:
         cs.evictions += int(regs[be.R_EVICTIONS]) - res.evic0
         res.demo0 = int(regs[be.R_DEMOTIONS])
         res.evic0 = int(regs[be.R_EVICTIONS])
+        self.counts["jit.make_space_ops"] += int(regs[be.R_MS_OPS]) - res.ms0
+        res.ms0 = int(regs[be.R_MS_OPS])
         rts = float(nsh + npr)                 # shortcut chases: 1 RT
         mf = np.nonzero(ev == be.EV_MISS_FILL)[0]
         if mf.size:

@@ -14,8 +14,8 @@ from .ref import (CNT_HIST_MAX, CUT_EMA, CUT_NAMES, CUT_NONE,
                   EV_MISS_ABSENT, EV_MISS_FILL, EV_PROMOTE, EV_SHORTCUT_HIT,
                   EV_VALUE_HIT, EV_WRITE, NUM_REGS, OP_READ, OP_WRITE,
                   PM_ABSENT, PM_INVALID, R_CLOCK, R_DEMOTIONS,
-                  R_EMA_DIRTY, R_EVICTIONS, R_NSHORT, R_NVALS, R_USED,
-                  R_ZSHORT, SHORTCUT_BYTES, TABLE_N,
+                  R_EMA_DIRTY, R_EVICTIONS, R_MS_OPS, R_NSHORT, R_NVALS,
+                  R_USED, R_ZSHORT, SHORTCUT_BYTES, TABLE_N,
                   VALUE_OVERHEAD_BYTES, build_promote_table,
                   fused_window_ref, init_state)
 
@@ -26,6 +26,6 @@ __all__ = [
     "EV_MISS_ABSENT", "EV_MISS_FILL", "EV_PROMOTE", "EV_SHORTCUT_HIT",
     "EV_VALUE_HIT", "EV_WRITE", "NUM_REGS", "OP_READ", "OP_WRITE",
     "PM_ABSENT", "PM_INVALID", "R_CLOCK", "R_DEMOTIONS", "R_EMA_DIRTY",
-    "R_EVICTIONS", "R_NSHORT", "R_NVALS", "R_USED", "R_ZSHORT",
-    "SHORTCUT_BYTES", "TABLE_N", "VALUE_OVERHEAD_BYTES",
+    "R_EVICTIONS", "R_MS_OPS", "R_NSHORT", "R_NVALS", "R_USED",
+    "R_ZSHORT", "SHORTCUT_BYTES", "TABLE_N", "VALUE_OVERHEAD_BYTES",
 ]

@@ -15,12 +15,14 @@ comparison is discretized into the host-built promote threshold table,
 see ``ref.build_promote_table``), so the compiled path cannot drift
 from the reference by a rounding flip.
 
-Four lowering choices keep each op to a few small launches; getting
+Five lowering choices keep each op to a few small launches; getting
 one wrong can turn them into whole-array memory traffic or multiply
 them.  ``tests/test_chip_compile.py`` checks the result on the program
-compiled for a TPU v5e at the served slot count: bounded fusions per
-op and per make-space iteration, no conditional and no whole-array
-copy or scatter in either loop, no large gather at entry.
+compiled for a TPU v5e at the served slot count: bounded launches per
+op and per make-space iteration, no register-array update and one
+block-array write per tree update in either loop, no conditional and
+no whole-array copy or scatter in either loop, no large gather at
+entry.
 
 * Victim selection.  The reference's lazy LRU/LFU heaps become two
   min-trees over (value, key) -- LRU over value-entry stamps, LFU over
@@ -33,13 +35,29 @@ copy or scatter in either loop, no large gather at entry.
   is one reduce over a reshape (no strided pairing, which lowers to
   gathers at ~36 M elements/s on the chip), and a leaf update is the
   same few launches at any slot count: an in-place write per leaf
-  lane, one reduce over the leaf's block, an in-place write per block
-  lane.  A binary tree costs a launch per level and lane as
-  per-level arrays, and a pass over the whole lane as one flat array
-  written by a scatter (a TPU scatter reads and writes its whole
-  operand).  Lanes stay separate 1-D arrays: a ``(n, lanes)`` layout
-  would be tiled ``(8, 128)`` on the TPU, its minor dimension padded
-  32x.
+  lane, one reduce over the leaf's block, one in-place column write
+  into the block array.  A binary tree costs a launch per level and
+  lane as per-level arrays, and a pass over the whole lane as one
+  flat array written by a scatter (a TPU scatter reads and writes its
+  whole operand).  Leaf lanes stay separate 1-D arrays: a ``(n,
+  lanes)`` layout would be tiled ``(8, 128)`` on the TPU, its minor
+  dimension padded 32x.  A tree's block minima are one ``[lanes,
+  n/f]`` array, lanes on the major axis, so an update writes one
+  column, not one block entry per lane.
+
+* Scalars where an array update would launch.  The registers ride
+  both loops as scalar carries, unpacked at entry and packed once at
+  exit: an update of a register array is a launch of its own, scalar
+  arithmetic is not.  An op's histogram moves land as one elementwise
+  add of one-hot deltas after make-space (which adds to the histogram
+  but never reads it), and ``events``/``out_ptr`` are the two rows of
+  one array, written one column per op.
+
+* No dead tree writes.  Taking the op's key out of both victim pools
+  matters only to make-space, so it is the first iteration of the
+  make-space loop, not a step of every op: an op that needs no victim
+  (nearly every hit, and every write or fill that fits) writes each of
+  its tree leaves once, after the loop.
 
 * Predication, not branching.  The per-op state machine is a
   straight-line body of masked scalar scatters.  ``lax.cond`` /
@@ -78,10 +96,10 @@ from jax import lax
 from .ref import (CNT_HIST_MAX, CUT_EMA, CUT_NONE, CUT_PREFETCH,
                   CUT_SEGCACHE, CUT_SPILL, CUT_TABLE, EV_MISS_ABSENT,
                   EV_MISS_FILL, EV_PROMOTE, EV_SHORTCUT_HIT,
-                  EV_VALUE_HIT, EV_WRITE, PM_ABSENT, PM_INVALID,
-                  R_CLOCK, R_DEMOTIONS, R_EMA_DIRTY, R_EVICTIONS,
-                  R_NSHORT, R_NVALS, R_USED, R_ZSHORT, SHORTCUT_BYTES,
-                  VALUE_OVERHEAD_BYTES)
+                  EV_VALUE_HIT, EV_WRITE, NUM_REGS, PM_ABSENT,
+                  PM_INVALID, R_CLOCK, R_DEMOTIONS, R_EMA_DIRTY,
+                  R_EVICTIONS, R_MS_OPS, R_NSHORT, R_NVALS, R_USED,
+                  R_ZSHORT, SHORTCUT_BYTES, VALUE_OVERHEAD_BYTES)
 
 _BIG = jnp.int32(2 ** 31 - 1)
 _SB = SHORTCUT_BYTES
@@ -94,13 +112,14 @@ def _i32(b):
 
 
 # ----- two-level min-trees over (value, key, *payloads) ------------------
-# A tree is (leaf lanes, block lanes).  Leaf lanes are (value,
+# A tree is (leaf lanes, block array).  Leaf lanes are (value,
 # *payloads), one 1-D array of n entries each; leaf i holds key i, never
 # materialized (a carried constant arange would cost XLA a full-array
 # copy per loop iteration).  The leaves split into contiguous blocks of
-# f = 2^ceil(log2(n)/2); block lanes are (value, key, *payloads) of each
-# block's (value, key) minimum, n/f entries each.  The root is the
-# minimum of the blocks, taken when asked for.
+# f = 2^ceil(log2(n)/2); the block array is [L, n/f], row j the j-th of
+# (value, key, *payloads) of each block's (value, key) minimum, so an
+# update writes one [L, 1] column.  The root is the minimum of the
+# blocks, taken when asked for.
 def _lexmin(lanes, axis):
     """(value, key, *payloads) of the (value, key) minimum along
     ``axis``: one variadic reduce."""
@@ -121,29 +140,40 @@ def _tree_build(vals, payloads=()):
     keys = jnp.arange(n, dtype=jnp.int32)
     blocks = _lexmin([l.reshape(n // f, f) for l in (vals, keys)
                       + tuple(payloads)], 1)
-    return leaves, blocks
+    return leaves, jnp.stack(blocks)
 
 
 def _tree_set(tree, k, val, pvals=()):
     """Set leaf k to val (+ payloads) and re-min its block: one
     in-place write per leaf lane, one reduce over the block's f
-    contiguous leaves, one in-place write per block lane -- whatever
-    the slot count.  (A scatter of several entries is no substitute:
-    on the TPU it passes over its whole operand.)"""
+    contiguous leaves, one in-place column write into the block array
+    -- whatever the slot count.  (A scatter of several entries is no
+    substitute: on the TPU it passes over its whole operand.)"""
     leaves, blocks = tree
-    f = leaves[0].shape[0] // blocks[0].shape[0]
+    f = leaves[0].shape[0] // blocks.shape[1]
     leaves = tuple(l.at[k].set(x) for l, x in zip(leaves, (val,)
                                                   + tuple(pvals)))
     b = k // f
     blk = [lax.dynamic_slice(l, (b * f,), (f,)) for l in leaves]
     keys = b * f + jnp.arange(f, dtype=jnp.int32)
     best = _lexmin([blk[0], keys] + blk[1:], 0)
-    return leaves, tuple(l.at[b].set(x) for l, x in zip(blocks, best))
+    return leaves, _write_column(blocks, best, b)
+
+
+def _write_column(a, vals, j):
+    """``a`` with column j set to the scalars ``vals``: one in-place
+    write.  The column is built elementwise (a stack of scalars would
+    lower to a concatenate and a layout copy)."""
+    row = lax.broadcasted_iota(jnp.int32, (len(vals), 1), 0)
+    col = jnp.broadcast_to(vals[-1], (len(vals), 1))
+    for r, v in enumerate(vals[:-1]):
+        col = jnp.where(row == r, v, col)
+    return lax.dynamic_update_slice(a, col, (jnp.int32(0), j))
 
 
 def _tree_min(tree):
     """(min value, its key, *payloads) -- the lazy-heap pop order."""
-    return _lexmin(tree[1], 0)
+    return _lexmin(tuple(tree[1]), 0)
 
 
 def _lru_set(tr, k, val, ln, cnt):
@@ -154,8 +184,17 @@ def _lfu_set(tr, k, val):
     return (tr[0], _tree_set(tr[1], k, val))
 
 
+def _hist_add(hist, deltas):
+    """``hist`` plus each (count, delta) of ``deltas`` at bucket
+    min(count, CNT_HIST_MAX): one elementwise add of one-hot deltas,
+    not one write per pair."""
+    b = jnp.arange(hist.shape[0], dtype=jnp.int32)
+    return hist + sum(_i32(b == jnp.minimum(c, _HM)) * d
+                      for c, d in deltas)
+
+
 # ----- make-space (mirrors ArrayDAC._make_space 1:1) ---------------------
-def _make_space(hist, regs, tr, need, cap):
+def _make_space(hist, regs, tr, need, cap, k, k_leaves):
     """Demote LRU values (reinsert as a shortcut when room remains),
     then evict LFU shortcuts, until ``need`` bytes fit.  ``need`` = 0
     degenerates to a no-op (used <= cap is an invariant), which is how
@@ -164,7 +203,15 @@ def _make_space(hist, regs, tr, need, cap):
     One predicated loop: demote strictly while values remain, then
     evict -- the same victim sequence as the reference's two loops.
     The carry holds only what the loop writes; victim metadata comes
-    from the tree roots (see the tree comment)."""
+    from the tree roots (see the tree comment).
+
+    The first iteration selects no victim: it sets the op's key k to
+    ``k_leaves`` = (LRU value, length, count, LFU value), which takes k
+    out of both victim pools (or keeps a hit's new stamp), so k can
+    never be selected against itself.  It leaves the registers as they
+    are, so a victim iteration always follows, and counts the op in
+    R_MS_OPS.  An op whose make-space finds room at once runs no
+    iteration and writes k's leaves once, after the loop."""
 
     def cond(c):
         r = c[1]
@@ -173,32 +220,39 @@ def _make_space(hist, regs, tr, need, cap):
 
     def body(c):
         hist, r, tr = c
-        dem = r[R_NVALS] > 0
+        r = list(r)
+        first = r[R_MS_OPS] == ms0
+        dem = ~first & (r[R_NVALS] > 0)
+        evi = ~first & (r[R_NVALS] == 0)
         _, v_d, ln, cv_d = _tree_min(tr[0])
         cv_e, v_e = _tree_min(tr[1])
         v = jnp.where(dem, v_d, v_e)
         cv = jnp.where(dem, cv_d, cv_e)
         used_d = r[R_USED] - (ln + _VOB)
         reins = dem & (used_d + _SB + need <= cap)
-        hist = hist.at[jnp.minimum(cv, _HM)].add(
-            _i32(reins) - _i32(~dem))
-        r = r.at[R_USED].set(
-            jnp.where(dem, used_d + _SB * _i32(reins),
-                      r[R_USED] - _SB))
-        r = r.at[R_NVALS].add(-_i32(dem))
-        r = r.at[R_DEMOTIONS].add(_i32(dem))
-        r = r.at[R_NSHORT].add(jnp.where(dem, _i32(reins),
-                                         jnp.int32(-1)))
-        r = r.at[R_ZSHORT].add(
-            (_i32(reins) - _i32(~dem)) * _i32(cv == 0))
-        r = r.at[R_EVICTIONS].add(_i32(~dem))
+        d_short = _i32(reins) - _i32(evi)
+        hist = hist.at[jnp.minimum(cv, _HM)].add(d_short)
+        r[R_USED] = jnp.where(dem, used_d + _SB * _i32(reins),
+                              r[R_USED] - _SB * _i32(evi))
+        r[R_NVALS] -= _i32(dem)
+        r[R_DEMOTIONS] += _i32(dem)
+        r[R_NSHORT] += d_short
+        r[R_ZSHORT] += d_short * _i32(cv == 0)
+        r[R_EVICTIONS] += _i32(evi)
+        r[R_MS_OPS] += _i32(first)
         # a demoted value leaves the LRU pool; an evicted (or
         # non-reinserted) shortcut leaves the LFU pool -- no reads
         # (an evicted shortcut's LRU leaf is already +inf)
-        tr = _lru_set(tr, v, _BIG, ln, cv)
-        tr = _lfu_set(tr, v, jnp.where(reins, cv, _BIG))
+        lru_k, ln_k, cnt_k, lfu_k = k_leaves
+        kv = jnp.where(first, k, v)
+        tr = _lru_set(tr, kv, jnp.where(first, lru_k, _BIG),
+                      jnp.where(first, ln_k, ln),
+                      jnp.where(first, cnt_k, cv))
+        tr = _lfu_set(tr, kv, jnp.where(
+            first, lfu_k, jnp.where(reins, cv, _BIG)))
         return hist, r, tr
 
+    ms0 = regs[R_MS_OPS]
     return lax.while_loop(cond, body, (hist, regs, tr))
 
 
@@ -245,18 +299,22 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
     nslots = kind0.shape[0]
     if nslots < 2 or nslots & (nslots - 1):
         raise ValueError("slot count must be a power of two >= 2")
-    # vectorized tree build: O(n) at entry, O(log n) per update after
+    # vectorized tree build: O(n) at entry, O(sqrt n) per update after
     tr0 = (_tree_build(jnp.where(kind0 == 2, state[2], _BIG),
                        payloads=(state[3], state[1])),
            _tree_build(jnp.where(kind0 == 1, state[1], _BIG)))
+    # the registers ride both loops as scalars (a register update is
+    # then scalar arithmetic, not a launch) and are packed once at exit
+    regs0 = [state[7][j] for j in range(NUM_REGS)]
 
     def cond(carry):
         i, cut = carry[0], carry[1]
         return (i < n) & (cut == CUT_NONE)
 
     def body(carry):
-        i, _, st, tr, events, out_ptr = carry
-        count, stamp, length, ptr, wrote, hist, regs = st
+        i, _, st, r, tr, rec = carry
+        count, stamp, length, ptr, wrote, hist = st
+        r = list(r)
         k = keys[i]
         # entry kind is derived, not carried: a key is a value entry
         # iff its LRU leaf is live, a shortcut iff its LFU leaf is --
@@ -279,8 +337,8 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
         is_shit = (~is_write) & was_s
         is_miss = (~is_write) & (kd == 0)
         c1 = c_old + 1
-        cut_s, promote = _promote_precheck(hist, regs, c1, ln_old,
-                                           cap, vmax)
+        cut_s, promote = _promote_precheck(hist, r, c1, ln_old, cap,
+                                           vmax)
         pp = pm_ptr[i]
         seg = (seg0[i] > 0) | (wrote[k] > 0)
         cut_m = jnp.select([seg, pp == PM_INVALID],
@@ -297,39 +355,35 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
         a_m = act & is_miss & (~absent)          # prefetch-backed fill
         pro = a_s & promote
 
-        # ---- phase B1: removal + hit bookkeeping (regs/hist/trees;
-        # the entry-field writes combine into one scatter in B3) ------
-        clock0 = regs[R_CLOCK]
-        regs = regs.at[R_USED].add(
-            -_i32(a_w) * (_i32(was_v) * (ln_old + _VOB)
-                          + _i32(was_s) * _SB)
-            - _i32(pro) * _SB)
-        regs = regs.at[R_NVALS].add(-_i32(a_w & was_v))
-        regs = regs.at[R_NSHORT].add(-_i32(a_w & was_s) - _i32(pro))
-        regs = regs.at[R_ZSHORT].add(
-            -_i32(a_w & was_s & (c_old == 0)) - _i32(a_s & (c1 == 1)))
-        regs = regs.at[R_CLOCK].add(_i32(a_v))
-        regs = regs.at[R_EMA_DIRTY].max(_i32(a_m))
-        hist = hist.at[jnp.minimum(c_old, _HM)].add(-_i32(a_w & was_s))
-        hist = hist.at[jnp.minimum(c1 - 1, _HM)].add(-_i32(a_s))
-        hist = hist.at[jnp.minimum(c1, _HM)].add(_i32(a_s & ~promote))
+        # ---- phase B1: removal + hit bookkeeping (registers; the
+        # histogram, entry-field and tree writes land in B3) ----------
+        clock0 = r[R_CLOCK]
+        r[R_USED] -= (_i32(a_w) * (_i32(was_v) * (ln_old + _VOB)
+                                   + _i32(was_s) * _SB)
+                      + _i32(pro) * _SB)
+        r[R_NVALS] -= _i32(a_w & was_v)
+        r[R_NSHORT] -= _i32(a_w & was_s) + _i32(pro)
+        r[R_ZSHORT] -= (_i32(a_w & was_s & (c_old == 0))
+                        + _i32(a_s & (c1 == 1)))
+        r[R_CLOCK] += _i32(a_v)
+        r[R_EMA_DIRTY] = jnp.maximum(r[R_EMA_DIRTY], _i32(a_m))
+        hist_b1 = [(c_old, -_i32(a_w & was_s)), (c1 - 1, -_i32(a_s)),
+                   (c1, _i32(a_s & ~promote))]
         wrote = wrote.at[k].set(wrote[k] | _i32(a_w))
-        # k leaves both victim pools before make-space, so it can
-        # never be selected against itself
+        # k's leaves once it has left both victim pools; make-space
+        # writes them before its first victim, B3 writes k's final ones
         leaf_lru1 = jnp.where(a_v, clock0,
                               jnp.where(a_w, _BIG,
                                         jnp.where(was_v, stamp_old,
                                                   _BIG)))
         cnt_pay = jnp.where(a_v, c1, c_old)
-        tr = _lru_set(tr, k, leaf_lru1, ln_old, cnt_pay)
         leaf_lfu1 = jnp.where(a_s & ~promote, c1,
                               jnp.where(a_w | pro, _BIG,
                                         jnp.where(was_s, c_old,
                                                   _BIG)))
-        tr = _lfu_set(tr, k, leaf_lfu1)
 
         # ---- phase B2: one unified make-space ------------------------
-        used1 = regs[R_USED]
+        used1 = r[R_USED]
         w_fits_v = used1 + write_bytes + _VOB <= cap
         ln_m = pm_len[i]
         m_fits_v = used1 + ln_m + _VOB <= cap
@@ -340,11 +394,14 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
                          jnp.where((a_w & ~w_fits_v)
                                    | (a_m & ~m_fits_v),
                                    jnp.int32(_SB), jnp.int32(0)))
-        hist, regs, tr = _make_space(hist, regs, tr, need, cap)
+        hist, r, tr = _make_space(hist, r, tr, need, cap, k,
+                                  (leaf_lru1, ln_old, cnt_pay,
+                                   leaf_lfu1))
+        r = list(r)
 
-        # ---- phase B3: insert / final entry-field scatter ------------
-        used2 = regs[R_USED]
-        clock2 = regs[R_CLOCK]
+        # ---- phase B3: insert / final entry-field writes -------------
+        used2 = r[R_USED]
+        clock2 = r[R_CLOCK]
         ins_any = a_w | pro | a_m
         p_ins = jnp.where(a_w, wptr[i], jnp.where(pro, p_old, pp))
         ln_ins = jnp.where(a_w, write_bytes,
@@ -364,18 +421,19 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
         stamp = stamp.at[k].set(stamp_f)
         ptr = ptr.at[k].set(jnp.where(doi, p_ins, p_old))
         length = length.at[k].set(jnp.where(doi, ln_ins, ln_old))
-        regs = regs.at[R_USED].add(
-            _i32(do_v) * (ln_ins + _VOB) + _i32(do_s) * _SB)
-        regs = regs.at[R_NVALS].add(_i32(do_v))
-        regs = regs.at[R_NSHORT].add(_i32(do_s))
-        regs = regs.at[R_ZSHORT].add(_i32(do_s & (cnt_ins == 0)))
-        regs = regs.at[R_CLOCK].add(_i32(do_v))
-        hist = hist.at[jnp.minimum(cnt_ins, _HM)].add(_i32(do_s))
+        r[R_USED] += _i32(do_v) * (ln_ins + _VOB) + _i32(do_s) * _SB
+        r[R_NVALS] += _i32(do_v)
+        r[R_NSHORT] += _i32(do_s)
+        r[R_ZSHORT] += _i32(do_s & (cnt_ins == 0))
+        r[R_CLOCK] += _i32(do_v)
+        # B1's histogram moves wait for this one write: make-space only
+        # adds to the histogram, it never reads it
+        hist = _hist_add(hist, hist_b1 + [(cnt_ins, _i32(do_s))])
         tr = _lru_set(tr, k, jnp.where(do_v, clock2, leaf_lru1),
                       jnp.where(do_v, ln_ins, ln_old),
                       jnp.where(do_v, cnt_ins, cnt_pay))
         tr = _lfu_set(tr, k, jnp.where(do_s, cnt_ins, leaf_lfu1))
-        st = (count, stamp, length, ptr, wrote, hist, regs)
+        st = (count, stamp, length, ptr, wrote, hist)
 
         # ---- phase B4: record + advance ------------------------------
         ev = jnp.where(
@@ -391,28 +449,28 @@ def _fused_window_impl(state, ops, keys, wptr, pm_ptr, pm_len, seg0,
         # hits read back the just-updated ptr array (same value -- a
         # hit never moves ptr) rather than the pre-op gather: reading
         # the old array here would anti-depend on the in-place ptr
-        # scatter above and cost XLA a whole-array defensive copy
+        # write above and cost XLA a whole-array defensive copy
         outp = jnp.where(is_write, wptr[i],
                          jnp.where(is_miss,
                                    jnp.where(absent, jnp.int32(-1),
                                              pp),
                                    ptr[k]))
-        events = events.at[i].set(ev)
-        out_ptr = out_ptr.at[i].set(outp)
-        return i + _i32(act), cut, st, tr, events, out_ptr
+        # events and out_ptr are the rows of one array: one write an op
+        rec = _write_column(rec, (ev, outp), i)
+        return i + _i32(act), cut, st, r, tr, rec
 
     w = ops.shape[0]
-    events = jnp.zeros(w, jnp.int32)
-    out_ptr = jnp.full(w, -1, jnp.int32)
-    i, cut, st, tr, events, out_ptr = lax.while_loop(
-        cond, body, (jnp.int32(0), jnp.int32(CUT_NONE), state[1:],
-                     tr0, events, out_ptr))
+    rec = jnp.stack([jnp.zeros(w, jnp.int32),
+                     jnp.full(w, -1, jnp.int32)])
+    i, cut, st, r, tr, rec = lax.while_loop(
+        cond, body, (jnp.int32(0), jnp.int32(CUT_NONE), state[1:7],
+                     regs0, tr0, rec))
     # the kind array was derived from the trees throughout; rebuild it
     # once, vectorized, for the returned state
     kind = jnp.where(tr[0][0][0] != _BIG, jnp.int32(2),
                      jnp.where(tr[1][0][0] != _BIG, jnp.int32(1),
                                jnp.int32(0)))
-    return i, (kind,) + st, events, out_ptr, cut
+    return i, (kind,) + st + (jnp.stack(r),), rec[0], rec[1], cut
 
 
 @functools.cache

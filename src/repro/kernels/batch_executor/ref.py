@@ -99,10 +99,11 @@ PM_ABSENT = -1          # index probe proved the key absent
 # >= TABLE_N fall back to the last row's sufficiency check or cut
 TABLE_N = 4096
 
-# register indices of the packed scalar state
+# register indices of the packed scalar state; R_MS_OPS counts the ops
+# whose make-space ran at least one demote or evict
 R_USED, R_CLOCK, R_ZSHORT, R_NVALS, R_NSHORT, R_EMA_DIRTY, \
-    R_DEMOTIONS, R_EVICTIONS = range(8)
-NUM_REGS = 8
+    R_DEMOTIONS, R_EVICTIONS, R_MS_OPS = range(9)
+NUM_REGS = 9
 
 
 def build_promote_table(avg_miss_rts: float,
@@ -154,12 +155,14 @@ class _S:
     """Mutable view over one state tuple (reference machine only)."""
 
     __slots__ = ("kind", "count", "stamp", "length", "ptr", "wrote",
-                 "hist", "regs", "cap")
+                 "hist", "regs", "cap", "op", "ms_op")
 
     def __init__(self, state, cap):
         (self.kind, self.count, self.stamp, self.length, self.ptr,
          self.wrote, self.hist, self.regs) = state
         self.cap = int(cap)
+        self.op = 0             # window position of the op being run
+        self.ms_op = -1         # the last op counted in R_MS_OPS
 
     def tuple(self):
         return (self.kind, self.count, self.stamp, self.length,
@@ -182,11 +185,19 @@ def _lfu_victim(s: _S):
     return int(ks[ct == m].min())
 
 
+def _note_make_space(s: _S) -> None:
+    """Count the running op in R_MS_OPS once, at its first victim."""
+    if s.ms_op != s.op:
+        s.ms_op = s.op
+        s.regs[R_MS_OPS] += 1
+
+
 def _make_space(s: _S, need: int) -> None:
     """``ArrayDAC._make_space``: demote LRU values (reinserting each as
     a shortcut when that still leaves room), then evict LFU shortcuts."""
     r = s.regs
     while r[R_USED] + need > s.cap and r[R_NVALS] > 0:
+        _note_make_space(s)
         v = _lru_victim(s)
         r[R_USED] -= s.length[v] + VALUE_OVERHEAD_BYTES
         r[R_NVALS] -= 1
@@ -201,6 +212,7 @@ def _make_space(s: _S, need: int) -> None:
                 r[R_ZSHORT] += 1
             s.hist[min(cv, CNT_HIST_MAX)] += 1
     while r[R_USED] + need > s.cap and r[R_NSHORT] > 0:
+        _note_make_space(s)
         v = _lfu_victim(s)
         cv = int(s.count[v])
         s.kind[v] = 0
@@ -290,6 +302,7 @@ def fused_window_ref(state, ops, keys, wptr, pm_ptr, pm_len, seg0, n,
     cut = CUT_NONE
     i = 0
     while i < int(n):
+        s.op = i
         k = int(keys[i])
         if ops[i] == OP_WRITE:
             p = int(wptr[i])

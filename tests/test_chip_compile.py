@@ -149,16 +149,26 @@ def _reached(comps, name):
     return [l for name in seen for l in comps[name]]
 
 
+def _shape(line):
+    """An instruction's result shape text, e.g. ``s32[4,2048]``."""
+    m = re.search(r"= (\w+\[[\d,]*\])", line)
+    return m.group(1) if m else None
+
+
 def test_fused_window_structure_on_tpu(smoke_window):
     """The TPU-compiled executor keeps the victim trees' costs flat in
-    the slot count: each op's loop body and each make-space iteration
-    launch a bounded number of fusions; neither body scatters into or
+    the slot count and each op to a few launches: each op's loop body
+    and each make-space iteration launch a bounded number of fusions
+    and in-place writes; neither updates a register array (the
+    registers are scalar carries) or writes a tree's block array more
+    than once (one column per tree update, and the op's own removal
+    from the trees lives in make-space); neither body scatters into or
     copies a whole state or tree array (a TPU scatter passes over its
     whole operand; a copy would come from a branch, from make-space
     reading what it does not write, or from hits re-reading the
     pre-update ptr); the entry builds the trees from contiguous blocks,
     with no large gather."""
-    _, compiled = smoke_window
+    nslots, compiled = smoke_window
     comps, entry = _computations(compiled.as_text())
     outer = [b for b in _while_bodies(comps[entry])
              if _while_bodies(comps[b])]
@@ -166,9 +176,17 @@ def test_fused_window_structure_on_tpu(smoke_window):
     inner = _while_bodies(comps[outer[0]])
     assert len(inner) == 1, inner
     big = 1 << 20
-    for body, most in ((outer[0], 120), (inner[0], 60)):
+    nblocks = nslots >> (nslots.bit_length() // 2)
+    for body, most in ((outer[0], 30), (inner[0], 14)):
         lines = comps[body]
-        assert len(_ops(lines, "fusion")) <= most, body
+        writes = _ops(lines, "fusion") + _ops(lines, "dynamic-update-slice")
+        assert len(writes) <= most, (body, len(writes))
+        assert not [l for l in writes
+                    if _shape(l) == "s32[%d]" % be.NUM_REGS], body
+        for lanes in (4, 2):       # the LRU and the LFU tree
+            block = [l for l in writes
+                     if _shape(l) == "s32[%d,%d]" % (lanes, nblocks)]
+            assert len(block) == 1, (body, block)
         assert not _ops(lines, "conditional"), body
         assert not [l for l in _ops(lines, "copy(-start)?")
                     if _elements(l) >= big], body

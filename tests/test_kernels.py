@@ -450,7 +450,6 @@ def _be_run_chain(seed, nslots=32, w=64, windows=3):
     state = be.init_state(z, z.copy(), z.copy(), z.copy(), z.copy(),
                           np.zeros(be.CNT_HIST_MAX + 1, np.int32),
                           0, 0, 0, 0, 0)
-    jstate = tuple(np.array(a) for a in state)
     for _ in range(windows):
         ops = rng.integers(0, 2, w).astype(np.int32)
         n = int(rng.integers(1, w + 1))
@@ -462,22 +461,27 @@ def _be_run_chain(seed, nslots=32, w=64, windows=3):
             p=[0.08, 0.2, 0.24, 0.24, 0.24]).astype(np.int32)
         pm_len = rng.integers(1, 300, w).astype(np.int32)
         seg0 = (rng.random(w) < 0.05).astype(np.int32)
-        ne_r, st_r, ev_r, op_r, cut_r = be.fused_window_ref(
-            state, ops, keys, wptr, pm_ptr, pm_len, seg0, n, cap, wb,
-            vmax)
-        j = be.fused_window(jstate, ops, keys, wptr, pm_ptr, pm_len,
-                            seg0, n, cap, wb, vmax)
-        ne_j, st_j = int(j[0]), j[1]
-        assert (ne_r, cut_r) == (ne_j, int(j[4]))
-        np.testing.assert_array_equal(ev_r[:ne_r],
-                                      np.array(j[2])[:ne_r])
-        np.testing.assert_array_equal(op_r[:ne_r],
-                                      np.array(j[3])[:ne_r])
-        for a, b in zip(st_r, st_j):
-            np.testing.assert_array_equal(a, np.array(b))
-        state = st_r
-        jstate = tuple(np.array(a) for a in st_r)
+        state, _ = _be_window(state, ops, keys, wptr, pm_ptr, pm_len,
+                              seg0, n, cap, wb, vmax)
     return state
+
+
+def _be_window(state, *args):
+    """One window through both engines from ``state``, asserting
+    bit-exact agreement (the cut reason, the executed prefix of the
+    event/out_ptr tapes, and every state array, registers included);
+    returns the oracle's state and events of the executed prefix."""
+    ne_r, st_r, ev_r, op_r, cut_r = be.fused_window_ref(state, *args)
+    j = be.fused_window(tuple(np.array(a) for a in state), *args)
+    ne_j, st_j = int(j[0]), j[1]
+    assert (ne_r, cut_r) == (ne_j, int(j[4]))
+    np.testing.assert_array_equal(ev_r[:ne_r],
+                                  np.array(j[2])[:ne_r])
+    np.testing.assert_array_equal(op_r[:ne_r],
+                                  np.array(j[3])[:ne_r])
+    for a, b in zip(st_r, st_j):
+        np.testing.assert_array_equal(a, np.array(b))
+    return st_r, ev_r[:ne_r]
 
 
 @pytest.mark.parametrize("seed", range(8))
@@ -494,6 +498,54 @@ def test_batch_executor_matches_oracle_chain_slots(seed, nslots):
     """The same contract from a two-slot cache (every op collides)
     to 1024 slots, where each victim tree splits into 32 blocks."""
     _be_run_chain(seed, nslots=nslots, windows=4)
+
+
+@pytest.mark.parametrize("mix", ["promote", "fill", "write"])
+@pytest.mark.parametrize("seed", [200, 201])
+def test_batch_executor_matches_oracle_make_space_mix(mix, seed):
+    """Windows at a cache a few values large that mix ops needing no
+    victim with ops whose make-space demotes or evicts -- shortcut
+    promotes, miss fills or writes -- agree bit-for-bit with the numpy
+    oracle, the count of make-space ops (R_MS_OPS) among the
+    registers; both kinds of op ran, and the mix's own op did."""
+    rng = np.random.default_rng(seed)
+    nslots, w, wb = 256, 128, 120
+    cap = int(rng.integers(1000, 2500))
+    vmax = be.build_promote_table(0.125)    # Eq. 1 promotes readily
+    z = np.zeros(nslots, np.int32)
+    state = be.init_state(z, z.copy(), z.copy(), z.copy(), z.copy(),
+                          np.zeros(be.CNT_HIST_MAX + 1, np.int32),
+                          0, 0, 0, 0, 0)
+    events, executed = [], 0
+    for t in range(10):
+        keys = rng.integers(0, nslots, w).astype(np.int32)
+        ops = np.zeros(w, np.int32)
+        if mix == "write":
+            ops = (rng.random(w) < 0.7).astype(np.int32)
+        elif mix == "promote" and t > 0:
+            # the entries the earlier windows left, mostly shortcuts (a
+            # miss fill would hold the next Eq. 1 decision to a cut)
+            short = np.flatnonzero(state[0] == 1)
+            vals = np.flatnonzero(state[0] == 2)
+            if short.size and vals.size:
+                keys = np.where(rng.random(w) < 0.8,
+                                rng.choice(short, w),
+                                rng.choice(vals, w)).astype(np.int32)
+        wptr = rng.integers(0, 10000, w).astype(np.int32)
+        pm_ptr = rng.integers(0, 10000, w).astype(np.int32)
+        pm_len = rng.integers(40, 400, w).astype(np.int32)
+        seg0 = np.zeros(w, np.int32)
+        state, ev = _be_window(state, ops, keys, wptr, pm_ptr, pm_len,
+                               seg0, w, cap, wb, vmax)
+        events.append(ev)
+        executed += ev.size
+        # the driver drops the staleness latch once it refolds the EMA
+        state[7][be.R_EMA_DIRTY] = 0
+    ms_ops = int(state[7][be.R_MS_OPS])
+    assert 0 < ms_ops < executed, (ms_ops, executed)
+    own = {"promote": be.EV_PROMOTE, "fill": be.EV_MISS_FILL,
+           "write": be.EV_WRITE}[mix]
+    assert (np.concatenate(events) == own).sum() > 0
 
 
 @pytest.mark.parametrize("nslots", [2, 4, 1024])

@@ -120,6 +120,26 @@ def test_batch_spans_nest_under_execute_batch():
     assert counts["jit.sync_bytes"] == counts["jit.upload_bytes"]
 
 
+def test_make_space_ops_count_device_ops_that_freed_room():
+    """``jit.make_space_ops`` counts the device ops whose make-space
+    demoted or evicted: some of a write-heavy batch at a full cache,
+    never more than the device ran, nor more than the demotions and
+    evictions."""
+    c = DinomoCluster(DINOMO, num_kns=2, cache_bytes=1 << 13,
+                      value_bytes=64, num_buckets=1 << 10,
+                      segment_capacity=64)
+    c.load(((k, f"v{k}") for k in range(800)), warm=True)
+    c.telemetry.reset()
+    c.reset_stats()
+    kinds, keys = _batch(5)
+    c.execute_batch(kinds, keys, values=lambda i: f"w{i}", engine="jit")
+    counts = c.telemetry.counts
+    moved = sum(kn.cache.stats.demotions + kn.cache.stats.evictions
+                for kn in c.kns.values())
+    assert 0 < counts["jit.make_space_ops"] <= counts["device_ops"]
+    assert counts["jit.make_space_ops"] <= moved
+
+
 def test_stall_merges_inside_a_batch_are_pool_merge_spans():
     c = DinomoCluster(DINOMO, num_kns=2, cache_bytes=1 << 16,
                       value_bytes=64, num_buckets=1 << 10,
